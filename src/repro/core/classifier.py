@@ -14,6 +14,7 @@ states. Validation accuracy is reported as measured (paper: 96.8%).
 """
 from __future__ import annotations
 
+import functools
 import time
 from dataclasses import dataclass
 from typing import List, Sequence, Tuple
@@ -181,13 +182,18 @@ def train_classifier(
     return params, report
 
 
+@functools.partial(jax.jit, static_argnums=(1,))
+def _proba(params: dict, cfg: ClassifierConfig, tokens, mask):
+    # one jitted function for every call: a per-call jit would compile
+    # again on each routed request
+    return jax.nn.softmax(classifier_logits(params, cfg, tokens, mask), -1)
+
+
 def predict_proba(params: dict, cfg: ClassifierConfig,
                   texts: Sequence[str]) -> np.ndarray:
     x, m = encode_prompts(texts, cfg.max_len)
     out = []
-    fn = jax.jit(lambda p, t, mm: jax.nn.softmax(
-        classifier_logits(p, cfg, t, mm), -1))
     for i in range(0, len(x), 256):
-        out.append(np.asarray(fn(params, jnp.asarray(x[i:i + 256]),
-                                 jnp.asarray(m[i:i + 256]))))
+        out.append(np.asarray(_proba(params, cfg, jnp.asarray(x[i:i + 256]),
+                                     jnp.asarray(m[i:i + 256]))))
     return np.concatenate(out) if out else np.zeros((0, cfg.num_classes))

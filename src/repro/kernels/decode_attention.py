@@ -39,13 +39,10 @@ def _dec_kernel(valid_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     n_blocks = pl.cdiv(live_max, block_k)
 
     def body(kj, _):
-        # leading indices as scalar arrays: plain python ints in a pl.load
-        # indexer are rejected by newer pallas interpreters
-        zero = jnp.int32(0)
-        k_blk = pl.load(k_ref, (zero, zero, pl.ds(kj * block_k, block_k),
-                                slice(None))).astype(jnp.float32)
-        v_blk = pl.load(v_ref, (zero, zero, pl.ds(kj * block_k, block_k),
-                                slice(None))).astype(jnp.float32)
+        k_blk = k_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(
+            jnp.float32)
+        v_blk = v_ref[0, 0, pl.ds(kj * block_k, block_k), :].astype(
+            jnp.float32)
         s = q @ k_blk.T                                     # (G, bk)
         slot = kj * block_k + jax.lax.iota(jnp.int32, block_k)
         s = jnp.where((slot < live_max)[None, :], s, NEG_INF)

@@ -15,8 +15,13 @@ Two kernels share the block-table discipline:
 
 
 Decode: same online-softmax structure as ``decode_attention.py``, but KV lives in
-a global block pool shaped (num_blocks, block_size, Hkv, D) shared by
+a global block pool shaped (num_blocks, Hkv, block_size, D) shared by
 every sequence, and each sequence names its blocks through a block table.
+The pool is head-major so that one kernel block — one KV head of one
+pool block, ``(block_size, D)`` — is the trailing two dims of the array:
+Mosaic requires a block's last two dims to be (8, 128)-divisible or
+equal to the array's, and a ``(.., 1, D)`` slice of a token-major
+``(.., block_size, Hkv, D)`` pool is neither.
 The grid walks (batch, kv-head, block-slot); the per-sequence block table
 is a scalar-prefetch operand, so each KV block's index map dereferences
 ``table[b, j]`` and the DMA engine streams exactly the blocks the
@@ -62,8 +67,8 @@ def _paged_kernel(bt_ref, valid_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(start < valid)
     def _attend():
         q = q_ref[0, 0].astype(jnp.float32) * scale         # (G, D)
-        k_blk = k_ref[0, :, 0, :].astype(jnp.float32)       # (bs, D)
-        v_blk = v_ref[0, :, 0, :].astype(jnp.float32)
+        k_blk = k_ref[0, 0].astype(jnp.float32)             # (bs, D)
+        v_blk = v_ref[0, 0].astype(jnp.float32)
         s = q @ k_blk.T                                     # (G, bs)
         slot = start + jax.lax.iota(jnp.int32, block_size)
         s = jnp.where((slot < valid)[None, :], s, NEG_INF)
@@ -84,8 +89,8 @@ def _paged_kernel(bt_ref, valid_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_decode_attention(
     q: jnp.ndarray,             # (B, Hq, D) — one token per sequence
-    k_pool: jnp.ndarray,        # (NB, BS, Hkv, D) global block pool
-    v_pool: jnp.ndarray,        # (NB, BS, Hkv, Dv)
+    k_pool: jnp.ndarray,        # (NB, Hkv, BS, D) global block pool
+    v_pool: jnp.ndarray,        # (NB, Hkv, BS, Dv)
     block_tables: jnp.ndarray,  # (B, NBseq) int32 pool block ids
     valid_len: jnp.ndarray,     # (B,) int32 — written tokens per sequence
     *,
@@ -93,7 +98,7 @@ def paged_decode_attention(
     interpret: bool = False,
 ) -> jnp.ndarray:
     B, Hq, D = q.shape
-    NB, BS, Hkv, Dv = v_pool.shape
+    NB, Hkv, BS, Dv = v_pool.shape
     NBseq = block_tables.shape[1]
     G = Hq // Hkv
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
@@ -107,10 +112,10 @@ def paged_decode_attention(
         grid=(B, Hkv, NBseq),
         in_specs=[
             pl.BlockSpec((1, 1, G, D), lambda b, h, j, bt, vl: (b, h, 0, 0)),
-            pl.BlockSpec((1, BS, 1, D),
-                         lambda b, h, j, bt, vl: (bt[b, j], 0, h, 0)),
-            pl.BlockSpec((1, BS, 1, Dv),
-                         lambda b, h, j, bt, vl: (bt[b, j], 0, h, 0)),
+            pl.BlockSpec((1, 1, BS, D),
+                         lambda b, h, j, bt, vl: (bt[b, j], h, 0, 0)),
+            pl.BlockSpec((1, 1, BS, Dv),
+                         lambda b, h, j, bt, vl: (bt[b, j], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, Dv),
                                lambda b, h, j, bt, vl: (b, h, 0, 0)),
@@ -172,8 +177,8 @@ def _chunk_prefill_kernel(info_ref, bt_ref, q_ref, kp_ref, vp_ref, kn_ref,
     # online softmax (m stays -inf and exp(s - m) saturates to 1).
     @pl.when((j < n_ctx) & (j * block_size < start))
     def _ctx():
-        k_blk = kp_ref[0, :, 0, :].astype(jnp.float32)      # (BS, D)
-        v_blk = vp_ref[0, :, 0, :].astype(jnp.float32)
+        k_blk = kp_ref[0, 0].astype(jnp.float32)            # (BS, D)
+        v_blk = vp_ref[0, 0].astype(jnp.float32)
         s = q @ k_blk.T                                     # (SbG, BS)
         slot = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, block_size), 1)
@@ -182,8 +187,8 @@ def _chunk_prefill_kernel(info_ref, bt_ref, q_ref, kp_ref, vp_ref, kn_ref,
     # the chunk itself: causal within the chunk, pads masked out
     @pl.when(j == n_ctx)
     def _self():
-        k_new = kn_ref[:, 0, :].astype(jnp.float32)         # (Sb, D)
-        v_new = vn_ref[:, 0, :].astype(jnp.float32)
+        k_new = kn_ref[0].astype(jnp.float32)               # (Sb, D)
+        v_new = vn_ref[0].astype(jnp.float32)
         s = q @ k_new.T                                     # (SbG, Sb)
         k_idx = jax.lax.broadcasted_iota(jnp.int32, (1, s.shape[1]), 1)
         live = (k_idx <= q_idx) & (k_idx < s_real)
@@ -198,10 +203,10 @@ def _chunk_prefill_kernel(info_ref, bt_ref, q_ref, kp_ref, vp_ref, kn_ref,
 
 def paged_prefill_attention(
     q: jnp.ndarray,             # (Sb, Hq, D) one sequence's chunk queries
-    k_pool: jnp.ndarray,        # (NB, BS, Hkv, D) global block pool
-    v_pool: jnp.ndarray,        # (NB, BS, Hkv, Dv)
-    k_new: jnp.ndarray,         # (Sb, Hkv, D) the chunk's fresh KV
-    v_new: jnp.ndarray,         # (Sb, Hkv, Dv)
+    k_pool: jnp.ndarray,        # (NB, Hkv, BS, D) global block pool
+    v_pool: jnp.ndarray,        # (NB, Hkv, BS, Dv)
+    k_new: jnp.ndarray,         # (Hkv, Sb, D) the chunk's fresh KV
+    v_new: jnp.ndarray,         # (Hkv, Sb, Dv)
     block_table: jnp.ndarray,   # (NBctx,) int32 blocks holding the context
     start,                      # scalar int32: tokens already cached
     s_real,                     # scalar int32: live chunk tokens (<= Sb)
@@ -215,7 +220,7 @@ def paged_prefill_attention(
     KV is an operand, not yet in the pool — the caller scatters it after
     (gather/compute/scatter, same split the paged engine prefill uses)."""
     Sb, Hq, D = q.shape
-    NB, BS, Hkv, Dv = v_pool.shape
+    NB, Hkv, BS, Dv = v_pool.shape
     if block_table.shape[0] == 0:       # no context yet: dummy (masked) block
         block_table = jnp.zeros((1,), jnp.int32)
     NBctx = block_table.shape[0]
@@ -233,14 +238,14 @@ def paged_prefill_attention(
         grid=(Hkv, NBctx + 1),
         in_specs=[
             pl.BlockSpec((1, Sb, G, D), lambda h, j, info, bt: (h, 0, 0, 0)),
-            pl.BlockSpec((1, BS, 1, D),
+            pl.BlockSpec((1, 1, BS, D),
                          lambda h, j, info, bt:
-                         (bt[jnp.minimum(j, NBctx - 1)], 0, h, 0)),
-            pl.BlockSpec((1, BS, 1, Dv),
+                         (bt[jnp.minimum(j, NBctx - 1)], h, 0, 0)),
+            pl.BlockSpec((1, 1, BS, Dv),
                          lambda h, j, info, bt:
-                         (bt[jnp.minimum(j, NBctx - 1)], 0, h, 0)),
-            pl.BlockSpec((Sb, 1, D), lambda h, j, info, bt: (0, h, 0)),
-            pl.BlockSpec((Sb, 1, Dv), lambda h, j, info, bt: (0, h, 0)),
+                         (bt[jnp.minimum(j, NBctx - 1)], h, 0, 0)),
+            pl.BlockSpec((1, Sb, D), lambda h, j, info, bt: (h, 0, 0)),
+            pl.BlockSpec((1, Sb, Dv), lambda h, j, info, bt: (h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, Sb, G, Dv),
                                lambda h, j, info, bt: (h, 0, 0, 0)),

@@ -57,15 +57,14 @@ def ref_paged_decode_attention(q, k_pool, v_pool, block_tables, valid_len,
     """Paged decode oracle: gather KV through the block table, then run the
     dense decode reference.
 
-    q: (B, Hq, D); pools: (NB, BS, Hkv, D); block_tables: (B, NBseq) int32
+    q: (B, Hq, D); pools: (NB, Hkv, BS, D); block_tables: (B, NBseq) int32
     ids into the pool's leading axis; valid_len: (B,) written tokens."""
     B = q.shape[0]
-    NB, BS, Hkv, D = k_pool.shape
-    # (B, NBseq, BS, Hkv, D) -> (B, Hkv, NBseq*BS, D)
+    Hkv = k_pool.shape[1]
+    # (B, NBseq, Hkv, BS, D) -> (B, Hkv, NBseq*BS, D)
     def gather(pool):
-        g = jnp.take(pool, block_tables, axis=0)
-        g = g.reshape(B, -1, Hkv, pool.shape[-1])
-        return jnp.moveaxis(g, 1, 2)
+        g = jnp.moveaxis(jnp.take(pool, block_tables, axis=0), 2, 1)
+        return g.reshape(B, Hkv, -1, pool.shape[-1])
 
     return ref_decode_attention(q, gather(k_pool), gather(v_pool), valid_len,
                                 ring=False, scale=scale)
@@ -80,18 +79,21 @@ def ref_paged_prefill_attention(q, k_pool, v_pool, k_new, v_new,
     limited to ``s_real`` live tokens — the rest is bucket padding).
 
     q: (Sb, Hq, D) chunk queries at global offset ``start``;
-    pools: (NB, BS, Hkv, D); k_new/v_new: (Sb, Hkv, D); block_table:
+    pools: (NB, Hkv, BS, D); k_new/v_new: (Hkv, Sb, D); block_table:
     (NBctx,) int32. Returns (Sb, Hq, Dv)."""
     Sb, Hq, D = q.shape
-    NB, BS, Hkv, _ = k_pool.shape
+    Hkv = k_pool.shape[1]
     G = Hq // Hkv
-    Dv = v_pool.shape[-1]
     scale = scale if scale is not None else 1.0 / math.sqrt(D)
-    ctx_k = jnp.take(k_pool, block_table, axis=0).reshape(-1, Hkv, D)
-    ctx_v = jnp.take(v_pool, block_table, axis=0).reshape(-1, Hkv, Dv)
+
+    def tokens(pool, new):          # context blocks + chunk, token-major
+        ctx = jnp.moveaxis(jnp.take(pool, block_table, axis=0), 2, 1)
+        ctx = ctx.reshape(-1, Hkv, pool.shape[-1])         # (CtxT, Hkv, D)
+        return ctx, jnp.concatenate([ctx, jnp.moveaxis(new, 0, 1)], axis=0)
+
+    ctx_k, k = tokens(k_pool, k_new)                     # (CtxT+Sb, Hkv, D)
+    _, v = tokens(v_pool, v_new)
     CtxT = ctx_k.shape[0]
-    k = jnp.concatenate([ctx_k, k_new], axis=0)         # (CtxT+Sb, Hkv, D)
-    v = jnp.concatenate([ctx_v, v_new], axis=0)
     k = jnp.repeat(k, G, axis=1)                        # (K, Hq, D)
     v = jnp.repeat(v, G, axis=1)
     s = jnp.einsum("qhd,khd->hqk", q.astype(jnp.float32),

@@ -1,8 +1,9 @@
 """Serving launcher: run the full Pick-and-Spin gateway on this host.
 
-Spins a model pool (reduced variants on CPU; the same code drives TPU
-deployments with full configs), routes a synthetic request stream, and
-prints per-model serving stats + lifecycle events.
+Spins a model pool (reduced float32 variants by default, for the CPU;
+``--published`` serves the registry's configs at their published widths
+and dtype, for the TPU), routes a synthetic request stream, and prints
+per-model serving stats + lifecycle events.
 
 Both planes speak serving API v2 (``repro.api``): typed
 ``CompletionRequest`` in, ``CompletionResponse`` out, shed requests as
@@ -27,11 +28,17 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import os
+import sys
 import time
+from pathlib import Path
+from typing import Dict
 
+import jax
 import numpy as np
 
 from repro.api import CompletionRequest
+from repro.configs.base import ModelConfig
 from repro.configs.registry import ARCHS
 from repro.core.gateway import Gateway, ServeFrontend
 from repro.core.orchestrator import SpinConfig
@@ -42,27 +49,56 @@ from repro.serving import SchedulerConfig
 from repro.data.benchmarks import generate_corpus
 
 DEFAULT_POOL = "smollm-360m,phi3-medium-14b,command-r-plus-104b"
+# sequence capacity of the reduced CPU configs: short synthetic prompts
+SMOKE_MAX_SEQ = 96
+CHECKOUT = Path(__file__).resolve().parents[3]
+
+
+def use_compile_cache() -> str:
+    """Place JAX's persistent compilation cache; entry points call this
+    once, before anything compiles. ``JAX_COMPILATION_CACHE_DIR``, when
+    set, is honoured as is (JAX reads it itself). Otherwise the cache
+    lives at a fixed ``<checkout>/.jax_cache`` — fixed because the path is
+    part of what a later run must find again. Returns the directory."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(CHECKOUT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+def build_models(pool: str, published: bool = False
+                 ) -> Dict[str, ModelConfig]:
+    """Configs the serve plane executes, from a comma-separated list of
+    registry names: ``reduced()`` float32 variants (the CPU default), or
+    with ``published`` the registry configs at their published widths and
+    dtype. Raises ValueError on an unknown name."""
+    models = {}
+    for name in pool.split(","):
+        name = name.strip()
+        if name not in ARCHS:
+            raise ValueError(f"unknown arch {name!r}; choose from "
+                             f"{sorted(ARCHS)}")
+        models[name] = (ARCHS[name] if published else
+                        dataclasses.replace(ARCHS[name].reduced(),
+                                            dtype="float32"))
+    return models
 
 
 def build_router(kind: str):
     if kind == "keyword":
         return KeywordRouter()
-    # semantic/hybrid need the trained classifier checkpoint from
-    # benchmarks; fall back to keyword with a notice if missing
-    try:
-        import os
-        import sys
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                        "../../../benchmarks"))
-        from common import get_classifier
-        sem, rep = get_classifier(log=None)
-        if kind == "distilbert":
-            return sem
-        from repro.core.router import HybridRouter
-        return HybridRouter(sem)
-    except Exception as e:  # noqa: BLE001
-        print(f"[serve] classifier unavailable ({e!r}); keyword routing")
-        return KeywordRouter()
+    # semantic/hybrid need the classifier the benchmarks train (and cache
+    # under benchmarks/artifacts/); the caller asked for it explicitly, so
+    # a failure to get it is an error, not a silent keyword fallback
+    sys.path.insert(0, str(CHECKOUT / "benchmarks"))
+    from common import get_classifier
+    sem, _ = get_classifier(log=None)
+    if kind == "distilbert":
+        return sem
+    from repro.core.router import HybridRouter
+    return HybridRouter(sem)
 
 
 def _print_results(results, wall, args, mode):
@@ -108,7 +144,7 @@ def _dump_metrics(frontend, path: str) -> None:
 
 def run_serial(pool, args) -> None:
     gw = Gateway(pool, router=build_router(args.router),
-                 profile=PROFILES[args.profile], max_seq=96)
+                 profile=PROFILES[args.profile], max_seq=args.max_seq)
     prompts = generate_corpus(max(args.requests, 64), seed=17)[: args.requests]
 
     t0 = time.perf_counter()
@@ -137,7 +173,8 @@ def run_concurrent(pool, args) -> None:
             specs.append(FaultSpec("step_error", rate=args.chaos_rate))
         faults = FaultPlan(specs, seed=args.chaos_seed)
     gw = ServeFrontend(pool, router=build_router(args.router),
-                       profile=PROFILES[args.profile], max_seq=96, spin=spin,
+                       profile=PROFILES[args.profile],
+                       max_seq=args.max_seq, spin=spin,
                        chunk_tokens=args.chunk_tokens or None,
                        step_token_budget=args.step_token_budget or None,
                        decode_burst=args.decode_burst,
@@ -186,6 +223,12 @@ def run_concurrent(pool, args) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--pool", default=DEFAULT_POOL)
+    ap.add_argument("--published", action="store_true",
+                    help="serve the registry configs at their published "
+                         "widths and dtype instead of reduced float32 "
+                         "variants (sized for an accelerator, not the CPU)")
+    ap.add_argument("--max-seq", type=int, default=SMOKE_MAX_SEQ,
+                    help="per-sequence token capacity (prompt + output)")
     ap.add_argument("--requests", type=int, default=24)
     ap.add_argument("--max-new-tokens", type=int, default=8)
     ap.add_argument("--profile", default="quality", choices=sorted(PROFILES))
@@ -240,14 +283,11 @@ def main() -> None:
                          "(--concurrent)")
     args = ap.parse_args()
 
-    pool = {}
-    for name in args.pool.split(","):
-        name = name.strip()
-        if name not in ARCHS:
-            raise SystemExit(f"unknown arch {name!r}; choose from "
-                             f"{sorted(ARCHS)}")
-        pool[name] = dataclasses.replace(ARCHS[name].reduced(),
-                                         dtype="float32")
+    use_compile_cache()
+    try:
+        pool = build_models(args.pool, args.published)
+    except ValueError as e:
+        raise SystemExit(str(e)) from None
 
     if args.spec_draft and args.spec_draft not in ARCHS:
         raise SystemExit(f"unknown spec draft arch {args.spec_draft!r}; "

@@ -313,29 +313,29 @@ def gqa_decode(params: Params, cfg: ModelConfig, x, cos, sin,
 # ---------------------------------------------------------------------------
 # paged GQA (block-pool KV cache; serving/kvpool.py owns the block ids)
 #
-# The cache is a GLOBAL pool of KV blocks shaped (num_blocks, block_size,
-# Hkv, D) shared by every sequence on the engine; a sequence's KV for
-# token position p lives at pool[table[p // bs], p % bs]. These jnp paths
-# define the semantics the Pallas kernel (kernels/paged_attention.py)
-# implements for the TPU hot path: they gather the leased blocks into
-# token order and reuse the dense attention math, so a paged engine is
-# arithmetically identical to the dense one.
+# The cache is a GLOBAL pool of KV blocks shaped (num_blocks, Hkv,
+# block_size, D) shared by every sequence on the engine; a sequence's KV
+# for token position p lives at pool[table[p // bs], :, p % bs]. The pool
+# is head-major because the Pallas kernels (kernels/paged_attention.py)
+# read one (block_size, D) tile per KV head, and Mosaic accepts a tile
+# only as the array's trailing dims. These jnp paths define the
+# semantics those kernels implement for the TPU hot path: they gather the
+# leased blocks into token order and reuse the dense attention math, so a
+# paged engine is arithmetically identical to the dense one.
 
 
-def _paged_parts(pool: Params):
-    k = pool["k"]
-    nb, bs = k.shape[0], k.shape[1]
-    flat = {name: arr.reshape((nb * bs,) + arr.shape[2:])
-            for name, arr in pool.items()}
-    return flat, nb, bs
+def _paged_geometry(pool: Params):
+    """(num_blocks, block_size) of a pool whose leaves are
+    (..., NB, Hkv, BS, D)."""
+    k = pool["stack"]["k"] if "stack" in pool else pool["k"]
+    return k.shape[-4], k.shape[-2]
 
 
 def _paged_write(pool: Params, k: jnp.ndarray, v: jnp.ndarray,
-                 flat_idx: jnp.ndarray) -> Params:
-    """Scatter new tokens into the pool. ``k``/``v``: (N, Hkv, D) with
-    leading dim matching ``flat_idx`` (token-flat pool indices; entries
-    >= num_blocks*block_size are dropped — padded/inactive writes)."""
-    flat, nb, bs = _paged_parts(pool)
+                 blk: jnp.ndarray, off: jnp.ndarray) -> Params:
+    """Scatter new tokens into the pool. ``k``/``v``: (N, Hkv, D);
+    ``blk``/``off``: (N,) pool block id and offset inside the block
+    (``blk`` >= num_blocks is dropped — padded/inactive writes)."""
     if "k_scale" in pool:
         from repro.serving.kvquant import quantize
         kq, ks = quantize(k)
@@ -343,32 +343,32 @@ def _paged_write(pool: Params, k: jnp.ndarray, v: jnp.ndarray,
         new = {"k": kq, "k_scale": ks, "v": vq, "v_scale": vs}
     else:
         new = {"k": k, "v": v}
-    out = {}
-    for name, arr in flat.items():
-        upd = new[name].astype(arr.dtype)
-        arr = arr.at[flat_idx].set(upd, mode="drop")
-        out[name] = arr.reshape(pool[name].shape)
-    return out
+    # two advanced indices split by a slice: the indexed view is
+    # (N, Hkv, D), the layout of the new tokens
+    return {name: arr.at[blk, :, off].set(new[name].astype(arr.dtype),
+                                          mode="drop")
+            for name, arr in pool.items()}
 
 
-def _paged_gather(cfg: ModelConfig, pool: Params, flat_idx: jnp.ndarray):
+def _paged_gather(cfg: ModelConfig, pool: Params, blk: jnp.ndarray,
+                  off: jnp.ndarray):
     """Read tokens back out of the pool in sequence order.
-    ``flat_idx``: (..., S) token-flat indices -> (kc, vc) (..., S, Hkv, D)."""
-    flat, _, _ = _paged_parts(pool)
-    gathered = {name: arr[flat_idx] for name, arr in flat.items()}
+    ``blk``/``off``: (..., S) block ids and in-block offsets ->
+    (kc, vc) (..., S, Hkv, D)."""
+    gathered = {name: arr[blk, :, off] for name, arr in pool.items()}
     return _unpack_kv(cfg, gathered)
 
 
 def paged_gather_ctx(cache: Params, table_ctx: jnp.ndarray) -> Params:
     """Lease-read the context blocks of one sequence out of the pool:
-    every leaf (..., NB, BS, H, D) -> (..., ctx*BS, H, D) in token order.
+    every leaf (..., NB, H, BS, D) -> (..., ctx*BS, H, D) in token order.
     A pure read — the pool buffer is never rewritten (that is the whole
     reason prefill splits into gather / compute / scatter)."""
     def take(leaf):
-        g = jnp.take(leaf, table_ctx, axis=leaf.ndim - 4)
-        shp = g.shape
-        merged = shp[:leaf.ndim - 4] + (shp[leaf.ndim - 4] * shp[leaf.ndim - 3],)
-        return g.reshape(merged + shp[leaf.ndim - 2:])
+        ax = leaf.ndim - 4                        # block axis
+        g = jnp.swapaxes(jnp.take(leaf, table_ctx, axis=ax), ax + 1, ax + 2)
+        shp = g.shape                             # (..., ctx, BS, H, D)
+        return g.reshape(shp[:ax] + (shp[ax] * shp[ax + 1],) + shp[ax + 2:])
 
     return jax.tree_util.tree_map(take, cache)
 
@@ -378,12 +378,11 @@ def paged_scatter(cache: Params, new_kv: Params, block_table: jnp.ndarray,
     """Write a request's freshly-computed suffix KV into its pool blocks
     (positions ``start .. start+s_real-1`` through ``block_table``).
     Compiled with the pool donated: the update aliases in place, costing
-    O(suffix), not O(pool). Leaves pair as (..., NB, BS, H, D) with
+    O(suffix), not O(pool). Leaves pair as (..., NB, H, BS, D) with
     (..., Sb, H, D)."""
     k0 = new_kv["stack"]["k"] if "stack" in new_kv else new_kv["k"]
     Sb = k0.shape[-3]
-    nb = (cache["stack"]["k"] if "stack" in cache else cache["k"]).shape[-4]
-    bs = (cache["stack"]["k"] if "stack" in cache else cache["k"]).shape[-3]
+    nb, bs = _paged_geometry(cache)
     pos = start + jnp.arange(Sb)
     blk = block_table[jnp.clip(pos // bs, 0, block_table.shape[0] - 1)]
     blk = jnp.where(jnp.arange(Sb) < s_real, blk, nb)          # drop pads
@@ -392,8 +391,10 @@ def paged_scatter(cache: Params, new_kv: Params, block_table: jnp.ndarray,
     def put(leaf, upd):
         upd = upd.astype(leaf.dtype)
         if leaf.ndim == 5:                        # stacked layers leading
-            return leaf.at[:, blk, off].set(upd, mode="drop")
-        return leaf.at[blk, off].set(upd, mode="drop")
+            # the split advanced indices lead the indexed view: (Sb, L, H, D)
+            return leaf.at[:, blk, :, off].set(jnp.moveaxis(upd, 1, 0),
+                                               mode="drop")
+        return leaf.at[blk, :, off].set(upd, mode="drop")
 
     return jax.tree_util.tree_map(put, cache, new_kv)
 
@@ -413,10 +414,11 @@ def gqa_paged_prefill(params: Params, cfg: ModelConfig, x, cos, sin,
 
     Kernel dispatch: under ``mosaic``/``interpret`` the chunk attends
     through ``kernels.paged_prefill_attention`` — the gathered context
-    is presented as ONE pool block (the kernel's block-table contract
-    covers any block size), the chunk's fresh KV rides as operands, and
-    one online softmax streams context + self causally. The jnp math
-    below is the ``reference`` trunk the kernel is validated against."""
+    is presented head-major as ONE pool block (the kernel's block-table
+    contract covers any block size), the chunk's fresh KV rides as
+    head-major operands, and one online softmax streams context + self
+    causally. The jnp math below is the ``reference`` trunk the kernel
+    is validated against."""
     B, Sb, _ = x.shape
     q, k, v = _proj_qkv(params, cfg, x)
     q = apply_rope(q, cos, sin)
@@ -427,7 +429,8 @@ def gqa_paged_prefill(params: Params, cfg: ModelConfig, x, cos, sin,
     if interpret is not None:
         from repro.kernels import ops
         o = ops.paged_prefill_attention(
-            q[0], kc[None], vc[None], k[0], v[0],
+            q[0], jnp.swapaxes(kc, 0, 1)[None], jnp.swapaxes(vc, 0, 1)[None],
+            jnp.swapaxes(k[0], 0, 1), jnp.swapaxes(v[0], 0, 1),
             jnp.zeros((1,), jnp.int32), start, s_real,
             interpret=interpret)[None]
         return _out_proj(params, cfg, o.astype(x.dtype)), _pack_kv(cfg, k[0], v[0])
@@ -471,12 +474,12 @@ def gqa_paged_decode(params: Params, cfg: ModelConfig, x, cos, sin,
     q, k, v = _proj_qkv(params, cfg, x)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    _, nb, bs = _paged_parts(pool)
+    nb, bs = _paged_geometry(pool)
     pos = jnp.asarray(pos, jnp.int32)
     safe = jnp.maximum(pos, 0)
     blk = jnp.take_along_axis(block_tables, (safe // bs)[:, None], axis=1)[:, 0]
-    flat = jnp.where(pos >= 0, blk * bs + safe % bs, nb * bs)
-    pool = _paged_write(pool, k[:, 0], v[:, 0], flat)
+    blk = jnp.where(pos >= 0, blk, nb)                     # drop inactive
+    pool = _paged_write(pool, k[:, 0], v[:, 0], blk, safe % bs)
     interpret = _kernel_dispatch(pool)
     if interpret is not None:
         from repro.kernels import ops
@@ -485,8 +488,8 @@ def gqa_paged_decode(params: Params, cfg: ModelConfig, x, cos, sin,
                                        interpret=interpret)[:, None]
         return _out_proj(params, cfg, o.astype(x.dtype)), pool
     t = jnp.arange(block_tables.shape[1] * bs)
-    gflat = jnp.take(block_tables, t // bs, axis=1) * bs + t % bs  # (B, Smax)
-    kc, vc = _paged_gather(cfg, pool, gflat)
+    kc, vc = _paged_gather(cfg, pool, jnp.take(block_tables, t // bs, axis=1),
+                           t % bs)                         # (B, Smax, ...)
     o = decode_attention_jnp(q, kc, vc, pos + 1)
     return _out_proj(params, cfg, o), pool
 
@@ -513,7 +516,7 @@ def gqa_paged_verify(params: Params, cfg: ModelConfig, x, cos, sin,
     q, k, v = _proj_qkv(params, cfg, x)
     q = apply_rope(q, cos, sin)
     k = apply_rope(k, cos, sin)
-    _, nb, bs = _paged_parts(pool)
+    nb, bs = _paged_geometry(pool)
     nbseq = block_tables.shape[1]
     pos = jnp.asarray(pos, jnp.int32)
     p = jnp.maximum(pos, 0)[:, None] + jnp.arange(S)[None, :]      # (B, S)
@@ -522,14 +525,14 @@ def gqa_paged_verify(params: Params, cfg: ModelConfig, x, cos, sin,
     ok = (pos[:, None] >= 0) & (p < nbseq * bs)
     if max_pos is not None:
         ok = ok & (p <= jnp.asarray(max_pos, jnp.int32)[:, None])
-    flat = jnp.where(ok, blk * bs + p % bs, nb * bs)               # drop
+    blk = jnp.where(ok, blk, nb)                                    # drop
     pool = _paged_write(pool, k.reshape(B * S, cfg.num_kv_heads,
                                         cfg.head_dim),
                         v.reshape(B * S, cfg.num_kv_heads, cfg.head_dim),
-                        flat.reshape(B * S))
+                        blk.reshape(B * S), (p % bs).reshape(B * S))
     t = jnp.arange(nbseq * bs)
-    gflat = jnp.take(block_tables, t // bs, axis=1) * bs + t % bs  # (B, Smax)
-    kc, vc = _paged_gather(cfg, pool, gflat)
+    kc, vc = _paged_gather(cfg, pool, jnp.take(block_tables, t // bs, axis=1),
+                           t % bs)                         # (B, Smax, ...)
     Hkv, G = cfg.num_kv_heads, cfg.num_heads // cfg.num_kv_heads
     scale = 1.0 / math.sqrt(cfg.head_dim)
     qg = q.reshape(B, S, Hkv, G, cfg.head_dim).astype(jnp.float32)

@@ -488,9 +488,10 @@ def lm_dense_verify(
 
 def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
                      dtype=None) -> Params:
-    """Global KV block pool: every leaf is (num_blocks, block_size, ...)
-    — one population of blocks shared by all sequences on the engine,
-    leased out through serving/kvpool.py block tables."""
+    """Global KV block pool: every leaf is (num_blocks, Hkv, block_size,
+    D or 1) — head-major, the layout the Pallas paged kernels tile — one
+    population of blocks shared by all sequences on the engine, leased
+    out through serving/kvpool.py block tables."""
     assert supports_paged(cfg), f"{cfg.name}: no paged cache for this family"
     dtype = dtype or _adtype(cfg)
     n_prefix = cfg.first_dense_layers if cfg.has_moe else 0
@@ -498,9 +499,9 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
 
     if cfg.kv_cache_dtype == "int8":
         def one(lead=()):
-            kv_shape = lead + (num_blocks, block_size, cfg.num_kv_heads,
+            kv_shape = lead + (num_blocks, cfg.num_kv_heads, block_size,
                                cfg.head_dim)
-            sc_shape = lead + (num_blocks, block_size, cfg.num_kv_heads, 1)
+            sc_shape = lead + (num_blocks, cfg.num_kv_heads, block_size, 1)
             return {
                 "k": jnp.zeros(kv_shape, jnp.int8),
                 "k_scale": jnp.zeros(sc_shape, jnp.float32),
@@ -509,7 +510,7 @@ def init_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
             }
     else:
         def one(lead=()):
-            shape = lead + (num_blocks, block_size, cfg.num_kv_heads,
+            shape = lead + (num_blocks, cfg.num_kv_heads, block_size,
                             cfg.head_dim)
             return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
@@ -524,7 +525,7 @@ def copy_paged_block(cache: Params, src, dst) -> Params:
     every layer and leaf of the pool (a shared prefix block a request
     must append into is copied first; see kvpool.RadixPrefixCache)."""
     def cp(arr):
-        axis = arr.ndim - 4          # block axis: (..., NB, BS, H, D/1)
+        axis = arr.ndim - 4          # block axis: (..., NB, H, BS, D/1)
         blk = jax.lax.dynamic_index_in_dim(arr, src, axis=axis)
         return jax.lax.dynamic_update_index_in_dim(arr, blk, dst, axis=axis)
 

@@ -73,8 +73,8 @@ def test_decode_attention(B, Hq, Hkv, S, D, ring, dtype):
 ])
 def test_paged_decode_attention(B, Hq, Hkv, D, BS, NBseq, NB, dtype):
     q = _rand((B, Hq, D), dtype)
-    k_pool = _rand((NB, BS, Hkv, D), dtype)
-    v_pool = _rand((NB, BS, Hkv, D), dtype)
+    k_pool = _rand((NB, Hkv, BS, D), dtype)
+    v_pool = _rand((NB, Hkv, BS, D), dtype)
     # each sequence leases distinct blocks scattered through the pool;
     # overlapping leases (shared prefix) are exercised by reusing seq 0's
     # first block for every sequence
@@ -100,10 +100,10 @@ def test_paged_decode_attention(B, Hq, Hkv, D, BS, NBseq, NB, dtype):
 def test_paged_prefill_attention(Sb, Hq, Hkv, D, BS, NBctx, NB, start,
                                  s_real, dtype):
     q = _rand((Sb, Hq, D), dtype)
-    k_pool = _rand((NB, BS, Hkv, D), dtype)
-    v_pool = _rand((NB, BS, Hkv, D), dtype)
-    k_new = _rand((Sb, Hkv, D), dtype)
-    v_new = _rand((Sb, Hkv, D), dtype)
+    k_pool = _rand((NB, Hkv, BS, D), dtype)
+    v_pool = _rand((NB, Hkv, BS, D), dtype)
+    k_new = _rand((Hkv, Sb, D), dtype)
+    v_new = _rand((Hkv, Sb, D), dtype)
     table = jnp.asarray(RNG.permutation(NB)[:NBctx], jnp.int32)
     out = ops.paged_prefill_attention(q, k_pool, v_pool, k_new, v_new,
                                       table, start, s_real, interpret=True)
@@ -124,22 +124,20 @@ def test_chunked_prefill_iterates_to_full_attention():
     k = _rand((S, Hkv, D), jnp.float32)
     v = _rand((S, Hkv, D), jnp.float32)
     NB = S // BS + 1
-    k_pool = jnp.zeros((NB, BS, Hkv, D), jnp.float32)
-    v_pool = jnp.zeros((NB, BS, Hkv, D), jnp.float32)
+    k_pool = jnp.zeros((NB, Hkv, BS, D), jnp.float32)
+    v_pool = jnp.zeros((NB, Hkv, BS, D), jnp.float32)
     table = jnp.asarray(RNG.permutation(NB - 1) + 1, jnp.int32)  # 0 unused
     outs = []
     for start in range(0, S, chunk):
         sl = slice(start, start + chunk)
         outs.append(ops.paged_prefill_attention(
-            q[sl], k_pool, v_pool, k[sl], v[sl], table, start, chunk,
-            interpret=True))
+            q[sl], k_pool, v_pool, jnp.moveaxis(k[sl], 0, 1),
+            jnp.moveaxis(v[sl], 0, 1), table, start, chunk, interpret=True))
         # scatter the chunk's KV into its blocks for the next iteration
-        flat = table[(start + np.arange(chunk)) // BS] * BS \
-            + (start + np.arange(chunk)) % BS
-        k_pool = k_pool.reshape(NB * BS, Hkv, D).at[flat].set(k[sl]) \
-            .reshape(NB, BS, Hkv, D)
-        v_pool = v_pool.reshape(NB * BS, Hkv, D).at[flat].set(v[sl]) \
-            .reshape(NB, BS, Hkv, D)
+        pos = start + np.arange(chunk)
+        blk, off = table[pos // BS], pos % BS
+        k_pool = k_pool.at[blk, :, off].set(k[sl])
+        v_pool = v_pool.at[blk, :, off].set(v[sl])
     got = jnp.concatenate(outs, axis=0)                  # (S, Hq, D)
     want = ref.ref_attention(q.transpose(1, 0, 2)[None],
                              k.transpose(1, 0, 2)[None],
@@ -160,8 +158,8 @@ def test_paged_decode_matches_dense_decode():
     vl = jnp.asarray([S - 5, 17], jnp.int32)
     # (B, Hkv, S, D) -> per-sequence blocks stacked into one pool
     def to_pool(c):
-        blocks = jnp.moveaxis(c, 1, 2).reshape(B, NBseq, BS, Hkv, D)
-        return blocks.reshape(B * NBseq, BS, Hkv, D)
+        blocks = jnp.moveaxis(c.reshape(B, Hkv, NBseq, BS, D), 2, 1)
+        return blocks.reshape(B * NBseq, Hkv, BS, D)
     tables = jnp.arange(B * NBseq, dtype=jnp.int32).reshape(B, NBseq)
     out = ops.paged_decode_attention(q, to_pool(kc), to_pool(vc), tables, vl,
                                      interpret=True)
