@@ -42,7 +42,7 @@ from repro.core.router import KeywordRouter
 from repro.core.scoring import PROFILES, OperatorProfile
 from repro.core.telemetry import Telemetry
 from repro.data.tokenizer import ByteTokenizer
-from repro.obs import Observability
+from repro.obs import Observability, phase
 from repro.serving import (GenResult, ReplicaPool, Request, RequestScheduler,
                            SamplingParams, SchedulerConfig, SpecConfig)
 
@@ -236,89 +236,92 @@ class ServeFrontend:
         """Route + select + enqueue. ALWAYS returns a handle: a shed
         request's handle is already resolved with ``finish_reason ==
         "shed"`` (structured backpressure, not ``None``)."""
-        if not isinstance(request, CompletionRequest):
-            request = CompletionRequest(
-                prompt=request, max_new_tokens=max_new_tokens,
-                deadline_s=deadline_s, priority=priority,
-                session_id=session_id, sampling=sampling)
-        now = time.perf_counter()
-        prompt_tokens = self.tok.encode(request.prompt)
-        sess = (self._sessions.get(request.session_id)
-                if request.session_id else None)
-        if sess is None:
-            decision = self.router.route(request.prompt)
-            sel = self.policy.select(decision, len(prompt_tokens),
-                                     request.max_new_tokens, self.profile)
-            model, backend = sel.entry.model, sel.entry.backend
-            tier = sel.entry.tier
-            if request.session_id:      # pin the service for later turns
-                sess = _Session(model, backend, tier)
-                self._sessions[request.session_id] = sess
-                self._bound_sessions()
-        else:
-            model, backend, tier = sess.model, sess.backend, sess.tier
-            self._sessions.move_to_end(request.session_id)
-        self.telemetry.record_request(model, now)
-        cfg = self.models[model]
-        tokens = [t % cfg.vocab_size for t in prompt_tokens]
-        if sess is not None:
-            tokens = sess.tokens + tokens
-        uid = self._uid
-        self._uid += 1
-        if self.obs is not None:
-            self.obs.tracer.on_submit(uid, model, backend, now)
-        ereq = Request(uid=uid, arrival_t=now, tokens=tokens,
-                       sampling=request.sampling or
-                       SamplingParams(max_new_tokens=request.max_new_tokens),
-                       deadline_s=request.deadline_s,
-                       priority=int(request.priority))
-        handle = CompletionHandle(self, uid, request, model=model,
-                                  backend=backend, tier=tier)
-        info = _Inflight(request, ereq, model, backend, tier,
-                         cold_mark=len(self.pool.cold_starts),
-                         turn=sess.turns if sess is not None else -1)
-        if not self.scheduler.enqueue(model, backend, ereq, now):
-            res = GenResult(uid=uid, prompt_len=len(tokens), shed=True)
-            handle._resolve(self._make_response(info, res))
-            self._remember(handle.response)
+        with phase("gateway.submit"):
+            if not isinstance(request, CompletionRequest):
+                request = CompletionRequest(
+                    prompt=request, max_new_tokens=max_new_tokens,
+                    deadline_s=deadline_s, priority=priority,
+                    session_id=session_id, sampling=sampling)
+            now = time.perf_counter()
+            prompt_tokens = self.tok.encode(request.prompt)
+            sess = (self._sessions.get(request.session_id)
+                    if request.session_id else None)
+            if sess is None:
+                decision = self.router.route(request.prompt)
+                sel = self.policy.select(decision, len(prompt_tokens),
+                                         request.max_new_tokens, self.profile)
+                model, backend = sel.entry.model, sel.entry.backend
+                tier = sel.entry.tier
+                if request.session_id:      # pin the service for later turns
+                    sess = _Session(model, backend, tier)
+                    self._sessions[request.session_id] = sess
+                    self._bound_sessions()
+            else:
+                model, backend, tier = sess.model, sess.backend, sess.tier
+                self._sessions.move_to_end(request.session_id)
+            self.telemetry.record_request(model, now)
+            cfg = self.models[model]
+            tokens = [t % cfg.vocab_size for t in prompt_tokens]
+            if sess is not None:
+                tokens = sess.tokens + tokens
+            uid = self._uid
+            self._uid += 1
+            if self.obs is not None:
+                self.obs.tracer.on_submit(uid, model, backend, now)
+            ereq = Request(uid=uid, arrival_t=now, tokens=tokens,
+                           sampling=request.sampling or
+                           SamplingParams(max_new_tokens=request.max_new_tokens),
+                           deadline_s=request.deadline_s,
+                           priority=int(request.priority))
+            handle = CompletionHandle(self, uid, request, model=model,
+                                      backend=backend, tier=tier)
+            info = _Inflight(request, ereq, model, backend, tier,
+                             cold_mark=len(self.pool.cold_starts),
+                             turn=sess.turns if sess is not None else -1)
+            if not self.scheduler.enqueue(model, backend, ereq, now):
+                res = GenResult(uid=uid, prompt_len=len(tokens), shed=True)
+                handle._resolve(self._make_response(info, res))
+                self._remember(handle.response)
+                return handle
+            self._inflight[uid] = info
+            self._handles[uid] = handle
             return handle
-        self._inflight[uid] = info
-        self._handles[uid] = handle
-        return handle
 
     # -- serve loop -----------------------------------------------------
     def step(self) -> List[CompletionResponse]:
         """One serve-loop iteration: Algorithm-1 tick when due, one
         scheduling + decode pass over the pool, streaming deltas pushed
         to their handles. Returns newly finished responses."""
-        now = time.perf_counter()
-        # replace quarantined replicas at STEP cadence, not tick cadence:
-        # a substitute owed between widely spaced Algorithm-1 ticks (or
-        # with autoscale off) must not wait for one. Idempotent with the
-        # tick's own repair path.
-        self.pool.replace_quarantined(now)
-        if self.config.autoscale and now >= self._next_tick:
-            before = {m: self.registry.model_replicas(m)
-                      for m in self.registry.models}
-            for m, target in self.orch.tick(now).items():
-                ev = OrchEvent(now, m, before[m], target)
-                self.orch_events.append(ev)
-                if self.obs is not None:
-                    self.obs.events.append("orch", t=now, model=m,
-                                           before=ev.before,
-                                           target=ev.target, kind=ev.kind)
-            self._next_tick = now + self.spin.tick_s
-        finished = self.scheduler.step(now)
-        for uid, token in self.scheduler.drain_deltas():
-            h = self._handles.get(uid)
-            if h is not None:            # warm-up probes have no handle
-                h._push_token(token)
-        out: List[CompletionResponse] = []
-        for _key, res in finished:
-            resp = self._finish(res)
-            if resp is not None:
-                out.append(resp)
-        return out
+        with phase("gateway.step"):
+            now = time.perf_counter()
+            # replace quarantined replicas at STEP cadence, not tick cadence:
+            # a substitute owed between widely spaced Algorithm-1 ticks (or
+            # with autoscale off) must not wait for one. Idempotent with the
+            # tick's own repair path.
+            self.pool.replace_quarantined(now)
+            if self.config.autoscale and now >= self._next_tick:
+                before = {m: self.registry.model_replicas(m)
+                          for m in self.registry.models}
+                for m, target in self.orch.tick(now).items():
+                    ev = OrchEvent(now, m, before[m], target)
+                    self.orch_events.append(ev)
+                    if self.obs is not None:
+                        self.obs.events.append("orch", t=now, model=m,
+                                               before=ev.before,
+                                               target=ev.target, kind=ev.kind)
+                self._next_tick = now + self.spin.tick_s
+            finished = self.scheduler.step(now)
+            out: List[CompletionResponse] = []
+            with phase("gateway.deliver"):
+                for uid, token in self.scheduler.drain_deltas():
+                    h = self._handles.get(uid)
+                    if h is not None:        # warm-up probes have no handle
+                        h._push_token(token)
+                for _key, res in finished:
+                    resp = self._finish(res)
+                    if resp is not None:
+                        out.append(resp)
+            return out
 
     def cancel(self, uid: int) -> bool:
         """Abort ``uid`` wherever it is (queue or mid-decode). The handle

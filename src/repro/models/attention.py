@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import Params, apply_rope, dense_init
+from repro.models.common import Params, apply_rope, cast_weight, dense_init
 
 NEG_INF = -1e30
 
@@ -198,13 +198,13 @@ def decode_attention_jnp(
 
 def _proj_qkv(p: Params, cfg: ModelConfig, x: jnp.ndarray):
     B, S, _ = x.shape
-    q = x @ p["wq"].astype(x.dtype)
-    k = x @ p["wk"].astype(x.dtype)
-    v = x @ p["wv"].astype(x.dtype)
+    q = x @ cast_weight(p["wq"], x.dtype)
+    k = x @ cast_weight(p["wk"], x.dtype)
+    v = x @ cast_weight(p["wv"], x.dtype)
     if "bq" in p:
-        q = q + p["bq"].astype(x.dtype)
-        k = k + p["bk"].astype(x.dtype)
-        v = v + p["bv"].astype(x.dtype)
+        q = q + cast_weight(p["bq"], x.dtype)
+        k = k + cast_weight(p["bk"], x.dtype)
+        v = v + cast_weight(p["bv"], x.dtype)
     q = q.reshape(B, S, cfg.num_heads, cfg.head_dim)
     k = k.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
     v = v.reshape(B, S, cfg.num_kv_heads, cfg.head_dim)
@@ -213,9 +213,9 @@ def _proj_qkv(p: Params, cfg: ModelConfig, x: jnp.ndarray):
 
 def _out_proj(p: Params, cfg: ModelConfig, o: jnp.ndarray):
     B, S = o.shape[:2]
-    o = o.reshape(B, S, -1) @ p["wo"].astype(o.dtype)
+    o = o.reshape(B, S, -1) @ cast_weight(p["wo"], o.dtype)
     if "bo" in p:
-        o = o + p["bo"].astype(o.dtype)
+        o = o + cast_weight(p["bo"], o.dtype)
     return o
 
 
@@ -336,18 +336,19 @@ def _paged_write(pool: Params, k: jnp.ndarray, v: jnp.ndarray,
     """Scatter new tokens into the pool. ``k``/``v``: (N, Hkv, D);
     ``blk``/``off``: (N,) pool block id and offset inside the block
     (``blk`` >= num_blocks is dropped — padded/inactive writes)."""
-    if "k_scale" in pool:
-        from repro.serving.kvquant import quantize
-        kq, ks = quantize(k)
-        vq, vs = quantize(v)
-        new = {"k": kq, "k_scale": ks, "v": vq, "v_scale": vs}
-    else:
-        new = {"k": k, "v": v}
-    # two advanced indices split by a slice: the indexed view is
-    # (N, Hkv, D), the layout of the new tokens
-    return {name: arr.at[blk, :, off].set(new[name].astype(arr.dtype),
-                                          mode="drop")
-            for name, arr in pool.items()}
+    with jax.named_scope("kv_write"):
+        if "k_scale" in pool:
+            from repro.serving.kvquant import quantize
+            kq, ks = quantize(k)
+            vq, vs = quantize(v)
+            new = {"k": kq, "k_scale": ks, "v": vq, "v_scale": vs}
+        else:
+            new = {"k": k, "v": v}
+        # two advanced indices split by a slice: the indexed view is
+        # (N, Hkv, D), the layout of the new tokens
+        return {name: arr.at[blk, :, off].set(new[name].astype(arr.dtype),
+                                              mode="drop")
+                for name, arr in pool.items()}
 
 
 def _paged_gather(cfg: ModelConfig, pool: Params, blk: jnp.ndarray,
@@ -355,8 +356,9 @@ def _paged_gather(cfg: ModelConfig, pool: Params, blk: jnp.ndarray,
     """Read tokens back out of the pool in sequence order.
     ``blk``/``off``: (..., S) block ids and in-block offsets ->
     (kc, vc) (..., S, Hkv, D)."""
-    gathered = {name: arr[blk, :, off] for name, arr in pool.items()}
-    return _unpack_kv(cfg, gathered)
+    with jax.named_scope("kv_gather"):
+        gathered = {name: arr[blk, :, off] for name, arr in pool.items()}
+        return _unpack_kv(cfg, gathered)
 
 
 def paged_gather_ctx(cache: Params, table_ctx: jnp.ndarray) -> Params:
@@ -370,7 +372,8 @@ def paged_gather_ctx(cache: Params, table_ctx: jnp.ndarray) -> Params:
         shp = g.shape                             # (..., ctx, BS, H, D)
         return g.reshape(shp[:ax] + (shp[ax] * shp[ax + 1],) + shp[ax + 2:])
 
-    return jax.tree_util.tree_map(take, cache)
+    with jax.named_scope("kv_gather"):
+        return jax.tree_util.tree_map(take, cache)
 
 
 def paged_scatter(cache: Params, new_kv: Params, block_table: jnp.ndarray,
@@ -383,10 +386,6 @@ def paged_scatter(cache: Params, new_kv: Params, block_table: jnp.ndarray,
     k0 = new_kv["stack"]["k"] if "stack" in new_kv else new_kv["k"]
     Sb = k0.shape[-3]
     nb, bs = _paged_geometry(cache)
-    pos = start + jnp.arange(Sb)
-    blk = block_table[jnp.clip(pos // bs, 0, block_table.shape[0] - 1)]
-    blk = jnp.where(jnp.arange(Sb) < s_real, blk, nb)          # drop pads
-    off = pos % bs
 
     def put(leaf, upd):
         upd = upd.astype(leaf.dtype)
@@ -396,7 +395,12 @@ def paged_scatter(cache: Params, new_kv: Params, block_table: jnp.ndarray,
                                                mode="drop")
         return leaf.at[blk, :, off].set(upd, mode="drop")
 
-    return jax.tree_util.tree_map(put, cache, new_kv)
+    with jax.named_scope("kv_scatter"):
+        pos = start + jnp.arange(Sb)
+        blk = block_table[jnp.clip(pos // bs, 0, block_table.shape[0] - 1)]
+        blk = jnp.where(jnp.arange(Sb) < s_real, blk, nb)      # drop pads
+        off = pos % bs
+        return jax.tree_util.tree_map(put, cache, new_kv)
 
 
 def gqa_paged_prefill(params: Params, cfg: ModelConfig, x, cos, sin,

@@ -15,6 +15,14 @@ import jax.numpy as jnp
 
 Params = dict
 
+
+def cast_weight(w: jnp.ndarray, dtype) -> jnp.ndarray:
+    """A stored weight in the compute dtype, under the ``weight_cast``
+    name scope (a no-op where it already has that dtype)."""
+    with jax.named_scope("weight_cast"):
+        return w.astype(dtype)
+
+
 # ---------------------------------------------------------------------------
 # init helpers
 

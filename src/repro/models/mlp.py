@@ -5,7 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import Params, act_fn, dense_init
+from repro.models.common import Params, act_fn, cast_weight, dense_init
 
 
 def init_ffn(cfg: ModelConfig, key, d_ff: int = 0, dtype=jnp.float32) -> Params:
@@ -28,7 +28,8 @@ def init_ffn(cfg: ModelConfig, key, d_ff: int = 0, dtype=jnp.float32) -> Params:
 def ffn(params: Params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
     act = act_fn(cfg.act)
     if "w_gate" in params:
-        h = act(x @ params["w_gate"].astype(x.dtype)) * (x @ params["w_up"].astype(x.dtype))
+        h = (act(x @ cast_weight(params["w_gate"], x.dtype))
+             * (x @ cast_weight(params["w_up"], x.dtype)))
     else:
-        h = act(x @ params["w_up"].astype(x.dtype))
-    return h @ params["w_down"].astype(x.dtype)
+        h = act(x @ cast_weight(params["w_up"], x.dtype))
+    return h @ cast_weight(params["w_down"], x.dtype)

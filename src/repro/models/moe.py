@@ -24,7 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
-from repro.models.common import Params, act_fn, dense_init
+from repro.models.common import Params, act_fn, cast_weight, dense_init
 
 
 def init_moe(cfg: ModelConfig, key, dtype=jnp.float32) -> Params:
@@ -99,9 +99,9 @@ def moe_ffn(
         jnp.where(keep[:, None], xt[tok_idx], 0).astype(x.dtype))
 
     # expert FFN on (E, C, d)
-    h = act(jnp.einsum("ecd,edf->ecf", buf, params["w_gate"].astype(x.dtype)))
-    h = h * jnp.einsum("ecd,edf->ecf", buf, params["w_up"].astype(x.dtype))
-    y = jnp.einsum("ecf,efd->ecd", h, params["w_down"].astype(x.dtype))
+    h = act(jnp.einsum("ecd,edf->ecf", buf, cast_weight(params["w_gate"], x.dtype)))
+    h = h * jnp.einsum("ecd,edf->ecf", buf, cast_weight(params["w_up"], x.dtype))
+    y = jnp.einsum("ecf,efd->ecd", h, cast_weight(params["w_down"], x.dtype))
 
     # gather + combine
     y_tok = y[flat_e, safe_pos]                                 # (T*K, d)

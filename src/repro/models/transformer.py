@@ -19,9 +19,9 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models import attention as attn
-from repro.models.common import (Params, embed_init, init_rmsnorm,
-                                 mrope_cos_sin, rmsnorm, rope_cos_sin,
-                                 stack_init)
+from repro.models.common import (Params, cast_weight, embed_init,
+                                 init_rmsnorm, mrope_cos_sin, rmsnorm,
+                                 rope_cos_sin, stack_init)
 from repro.models.mlp import ffn, init_ffn
 from repro.models.moe import init_moe, moe_ffn
 
@@ -98,10 +98,11 @@ def _block_train(cfg: ModelConfig, moe: bool, q_chunk: int, moe_cf=1.25):
 
 def embed_tokens(params: Params, cfg: ModelConfig, tokens: jnp.ndarray,
                  extra_embeds: Optional[jnp.ndarray] = None) -> jnp.ndarray:
-    h = params["embed"][tokens].astype(_adtype(cfg))
-    if extra_embeds is not None:
-        h = jnp.concatenate([extra_embeds.astype(h.dtype), h], axis=1)
-    return h
+    with jax.named_scope("embed"):
+        h = params["embed"][tokens].astype(_adtype(cfg))
+        if extra_embeds is not None:
+            h = jnp.concatenate([extra_embeds.astype(h.dtype), h], axis=1)
+        return h
 
 
 def _adtype(cfg: ModelConfig):
@@ -109,11 +110,12 @@ def _adtype(cfg: ModelConfig):
 
 
 def unembed(params: Params, cfg: ModelConfig, h: jnp.ndarray) -> jnp.ndarray:
-    w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-    logits = h.astype(jnp.float32) @ w.astype(jnp.float32).T
-    if cfg.logit_softcap:
-        logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
-    return logits
+    with jax.named_scope("unembed"):
+        w = params["embed"] if cfg.tie_embeddings else params["lm_head"]
+        logits = h.astype(jnp.float32) @ cast_weight(w, jnp.float32).T
+        if cfg.logit_softcap:
+            logits = cfg.logit_softcap * jnp.tanh(logits / cfg.logit_softcap)
+        return logits
 
 
 def lm_forward(
@@ -153,6 +155,59 @@ def lm_forward(
 
 
 # ---------------------------------------------------------------------------
+# the serving trunk: layers shared by prefill, decode and verify
+
+
+def _layer(cfg: ModelConfig, lp: Params, h, attend, moe_cf):
+    """One pre-norm layer: ``attend(attn params, x) -> (out, kv)``, then
+    the FFN (dense or MoE)."""
+    x = rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
+    with jax.named_scope("attn"):
+        o, kv = attend(lp["attn"], x)
+    h = h + o
+    x = rmsnorm(lp["ffn_norm"], h, cfg.norm_eps)
+    with jax.named_scope("mlp"):
+        if cfg.has_moe and "router" in lp["ffn"]:
+            y, _ = moe_ffn(lp["ffn"], cfg, x, capacity_factor=moe_cf)
+        else:
+            y = ffn(lp["ffn"], cfg, x)
+    return h + y, kv
+
+
+def _trunk(params: Params, cfg: ModelConfig, h, cache: Optional[Params],
+           attend, moe_cf) -> Tuple[jnp.ndarray, Params]:
+    """Every layer, then the final norm: the unstacked prefix layers,
+    then one scan over the stack. ``attend(attn params, x, layer state)
+    -> (out, kv)``; ``cache`` holds each layer's state split the same
+    way, or is None (prefill from nothing). Returns (h, the layers' kv
+    as ``{"stack": ..., "prefix": [...]}``)."""
+    prefix = params.get("prefix_layers", [])
+    states = ([None] * len(prefix) if cache is None
+              else cache.get("prefix", []))
+
+    def block(lp, h, c):
+        return _layer(cfg, lp, h, lambda p, x: attend(p, x, c), moe_cf)
+
+    new_prefix = []
+    for lp, c in zip(prefix, states):
+        h, c = block(lp, h, c)
+        new_prefix.append(c)
+
+    def scan_body(h, xs):
+        lp, c = xs
+        return block(lp, h, c)
+
+    h, new_stack = jax.lax.scan(
+        scan_body, h,
+        (params["layers"], None if cache is None else cache["stack"]))
+    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    new = {"stack": new_stack}
+    if new_prefix:
+        new["prefix"] = new_prefix
+    return h, new
+
+
+# ---------------------------------------------------------------------------
 # prefill
 
 
@@ -169,39 +224,13 @@ def lm_prefill(
         positions = jnp.arange(S)[None, :].repeat(B, 0)
     cos, sin = _cos_sin(cfg, positions)
     eff_len = cache_len if cfg.sliding_window is None else cfg.sliding_window
+    prefill = attn.mla_prefill if cfg.attention_type == "mla" else attn.gqa_prefill
 
-    def block_prefill(lp, h):
-        x = rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
-        if cfg.attention_type == "mla":
-            o, cache = attn.mla_prefill(lp["attn"], cfg, x, cos, sin, eff_len,
-                                        q_chunk=q_chunk)
-        else:
-            o, cache = attn.gqa_prefill(lp["attn"], cfg, x, cos, sin, eff_len,
-                                        q_chunk=q_chunk)
-        h = h + o
-        x = rmsnorm(lp["ffn_norm"], h, cfg.norm_eps)
-        if cfg.has_moe and "router" in lp["ffn"]:
-            y, _ = moe_ffn(lp["ffn"], cfg, x, capacity_factor=moe_cf)
-        else:
-            y = ffn(lp["ffn"], cfg, x)
-        return h + y, cache
+    def attend(p, x, _):
+        return prefill(p, cfg, x, cos, sin, eff_len, q_chunk=q_chunk)
 
-    prefix_caches = []
-    for lp in params.get("prefix_layers", []):
-        h, c = block_prefill(lp, h)
-        prefix_caches.append(c)
-
-    def scan_body(h, lp):
-        h, cache = block_prefill(lp, h)
-        return h, cache
-
-    h, stack_cache = jax.lax.scan(scan_body, h, params["layers"])
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = unembed(params, cfg, h[:, -1])
-    cache = {"stack": stack_cache}
-    if prefix_caches:
-        cache["prefix"] = prefix_caches
-    return logits, cache
+    h, cache = _trunk(params, cfg, h, None, attend, moe_cf)
+    return unembed(params, cfg, h[:, -1]), cache
 
 
 # ---------------------------------------------------------------------------
@@ -214,44 +243,19 @@ def lm_decode(
 ) -> Tuple[jnp.ndarray, Params]:
     """One-token step. token: (B, 1) int32; pos: scalar int32 global index.
     Returns (logits (B, V), new cache)."""
-    h = params["embed"][token].astype(_adtype(cfg))
+    h = embed_tokens(params, cfg, token)
     B = h.shape[0]
     if positions is None:
         p_ = jnp.asarray(pos, jnp.int32)
         positions = (jnp.full((B, 1), p_) if p_.ndim == 0 else p_[:, None])
     cos, sin = _cos_sin(cfg, positions)
+    decode = attn.mla_decode if cfg.attention_type == "mla" else attn.gqa_decode
 
-    def block_decode(lp, h, c):
-        x = rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
-        if cfg.attention_type == "mla":
-            o, c = attn.mla_decode(lp["attn"], cfg, x, cos, sin, c, pos)
-        else:
-            o, c = attn.gqa_decode(lp["attn"], cfg, x, cos, sin, c, pos)
-        h = h + o
-        x = rmsnorm(lp["ffn_norm"], h, cfg.norm_eps)
-        if cfg.has_moe and "router" in lp["ffn"]:
-            y, _ = moe_ffn(lp["ffn"], cfg, x, capacity_factor=moe_cf)
-        else:
-            y = ffn(lp["ffn"], cfg, x)
-        return h + y, c
+    def attend(p, x, c):
+        return decode(p, cfg, x, cos, sin, c, pos)
 
-    new_prefix = []
-    for lp, c in zip(params.get("prefix_layers", []), cache.get("prefix", [])):
-        h, c = block_decode(lp, h, c)
-        new_prefix.append(c)
-
-    def scan_body(h, xs):
-        lp, c = xs
-        h, c = block_decode(lp, h, c)
-        return h, c
-
-    h, new_stack = jax.lax.scan(scan_body, h, (params["layers"], cache["stack"]))
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = unembed(params, cfg, h[:, -1])
-    new_cache = {"stack": new_stack}
-    if new_prefix:
-        new_cache["prefix"] = new_prefix
-    return logits, new_cache
+    h, new_cache = _trunk(params, cfg, h, cache, attend, moe_cf)
+    return unembed(params, cfg, h[:, -1]), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -305,36 +309,12 @@ def lm_paged_prefill(
     positions = (start + jnp.arange(Sb))[None, :]
     cos, sin = _cos_sin(cfg, positions)
 
-    def block(lp, h, c):
-        x = rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
-        o, kv = attn.gqa_paged_prefill(lp["attn"], cfg, x, cos, sin, c,
-                                       start, s_real)
-        h = h + o
-        x = rmsnorm(lp["ffn_norm"], h, cfg.norm_eps)
-        if cfg.has_moe and "router" in lp["ffn"]:
-            y, _ = moe_ffn(lp["ffn"], cfg, x, capacity_factor=moe_cf)
-        else:
-            y = ffn(lp["ffn"], cfg, x)
-        return h + y, kv
+    def attend(p, x, c):
+        return attn.gqa_paged_prefill(p, cfg, x, cos, sin, c, start, s_real)
 
-    new_prefix = []
-    for lp, c in zip(params.get("prefix_layers", []), ctx_kv.get("prefix", [])):
-        h, kv = block(lp, h, c)
-        new_prefix.append(kv)
-
-    def scan_body(h, xs):
-        lp, c = xs
-        h, kv = block(lp, h, c)
-        return h, kv
-
-    h, new_stack = jax.lax.scan(scan_body, h,
-                                (params["layers"], ctx_kv["stack"]))
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
+    h, new_kv = _trunk(params, cfg, h, ctx_kv, attend, moe_cf)
     h_last = jax.lax.dynamic_slice_in_dim(h, jnp.maximum(s_real - 1, 0), 1,
                                           axis=1)[:, 0]
-    new_kv = {"stack": new_stack}
-    if new_prefix:
-        new_kv["prefix"] = new_prefix
     return unembed(params, cfg, h_last), new_kv
 
 
@@ -353,40 +333,17 @@ def lm_paged_decode(
     """One-token step against the block pool. token: (B, 1) int32;
     block_tables: (B, NBseq); pos: (B,) global token index, -1 for
     inactive slots. Returns (logits (B, V), updated pool)."""
-    h = params["embed"][token].astype(_adtype(cfg))
+    h = embed_tokens(params, cfg, token)
     pos = jnp.asarray(pos, jnp.int32)
     positions = jnp.maximum(pos, 0)[:, None]
     cos, sin = _cos_sin(cfg, positions)
 
-    def block(lp, h, c):
-        x = rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
-        o, c = attn.gqa_paged_decode(lp["attn"], cfg, x, cos, sin, c,
-                                     block_tables, pos)
-        h = h + o
-        x = rmsnorm(lp["ffn_norm"], h, cfg.norm_eps)
-        if cfg.has_moe and "router" in lp["ffn"]:
-            y, _ = moe_ffn(lp["ffn"], cfg, x, capacity_factor=moe_cf)
-        else:
-            y = ffn(lp["ffn"], cfg, x)
-        return h + y, c
+    def attend(p, x, c):
+        return attn.gqa_paged_decode(p, cfg, x, cos, sin, c, block_tables,
+                                     pos)
 
-    new_prefix = []
-    for lp, c in zip(params.get("prefix_layers", []), cache.get("prefix", [])):
-        h, c = block(lp, h, c)
-        new_prefix.append(c)
-
-    def scan_body(h, xs):
-        lp, c = xs
-        h, c = block(lp, h, c)
-        return h, c
-
-    h, new_stack = jax.lax.scan(scan_body, h, (params["layers"], cache["stack"]))
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    logits = unembed(params, cfg, h[:, -1])
-    new_cache = {"stack": new_stack}
-    if new_prefix:
-        new_cache["prefix"] = new_prefix
-    return logits, new_cache
+    h, new_cache = _trunk(params, cfg, h, cache, attend, moe_cf)
+    return unembed(params, cfg, h[:, -1]), new_cache
 
 
 # ---------------------------------------------------------------------------
@@ -396,8 +353,8 @@ def lm_paged_decode(
 # its last sampled token plus K drafted tokens in ONE forward and gets
 # logits back at EVERY position (the chunk-prefill trunk computes the
 # full hidden state too, but unembeds only the last live token — verify
-# needs them all, so these wrappers share the block/scan structure and
-# differ only in the attention primitive and the final unembed).
+# needs them all, so these wrappers share the layers and differ only in
+# the attention primitive and the final unembed).
 
 
 def lm_paged_verify(
@@ -415,33 +372,11 @@ def lm_paged_verify(
     positions = jnp.maximum(pos, 0)[:, None] + jnp.arange(S)[None, :]
     cos, sin = _cos_sin(cfg, positions)
 
-    def block(lp, h, c):
-        x = rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
-        o, c = attn.gqa_paged_verify(lp["attn"], cfg, x, cos, sin, c,
-                                     block_tables, pos, max_pos)
-        h = h + o
-        x = rmsnorm(lp["ffn_norm"], h, cfg.norm_eps)
-        if cfg.has_moe and "router" in lp["ffn"]:
-            y, _ = moe_ffn(lp["ffn"], cfg, x, capacity_factor=moe_cf)
-        else:
-            y = ffn(lp["ffn"], cfg, x)
-        return h + y, c
+    def attend(p, x, c):
+        return attn.gqa_paged_verify(p, cfg, x, cos, sin, c, block_tables,
+                                     pos, max_pos)
 
-    new_prefix = []
-    for lp, c in zip(params.get("prefix_layers", []), cache.get("prefix", [])):
-        h, c = block(lp, h, c)
-        new_prefix.append(c)
-
-    def scan_body(h, xs):
-        lp, c = xs
-        h, c = block(lp, h, c)
-        return h, c
-
-    h, new_stack = jax.lax.scan(scan_body, h, (params["layers"], cache["stack"]))
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    new_cache = {"stack": new_stack}
-    if new_prefix:
-        new_cache["prefix"] = new_prefix
+    h, new_cache = _trunk(params, cfg, h, cache, attend, moe_cf)
     return unembed(params, cfg, h), new_cache
 
 
@@ -457,32 +392,10 @@ def lm_dense_verify(
     positions = jnp.maximum(pos, 0)[:, None] + jnp.arange(S)[None, :]
     cos, sin = _cos_sin(cfg, positions)
 
-    def block(lp, h, c):
-        x = rmsnorm(lp["attn_norm"], h, cfg.norm_eps)
-        o, c = attn.gqa_dense_verify(lp["attn"], cfg, x, cos, sin, c, pos)
-        h = h + o
-        x = rmsnorm(lp["ffn_norm"], h, cfg.norm_eps)
-        if cfg.has_moe and "router" in lp["ffn"]:
-            y, _ = moe_ffn(lp["ffn"], cfg, x, capacity_factor=moe_cf)
-        else:
-            y = ffn(lp["ffn"], cfg, x)
-        return h + y, c
+    def attend(p, x, c):
+        return attn.gqa_dense_verify(p, cfg, x, cos, sin, c, pos)
 
-    new_prefix = []
-    for lp, c in zip(params.get("prefix_layers", []), cache.get("prefix", [])):
-        h, c = block(lp, h, c)
-        new_prefix.append(c)
-
-    def scan_body(h, xs):
-        lp, c = xs
-        h, c = block(lp, h, c)
-        return h, c
-
-    h, new_stack = jax.lax.scan(scan_body, h, (params["layers"], cache["stack"]))
-    h = rmsnorm(params["final_norm"], h, cfg.norm_eps)
-    new_cache = {"stack": new_stack}
-    if new_prefix:
-        new_cache["prefix"] = new_prefix
+    h, new_cache = _trunk(params, cfg, h, cache, attend, moe_cf)
     return unembed(params, cfg, h), new_cache
 
 

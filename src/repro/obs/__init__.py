@@ -20,7 +20,7 @@ from repro.obs.flight import FlightConfig, FlightRecorder  # noqa: F401
 from repro.obs.metrics import (DEFAULT_BUCKETS, Counter, Gauge,  # noqa: F401
                                Histogram, MetricsRegistry, log_buckets,
                                snapshot_quantile)
-from repro.obs.trace import Span, Tracer  # noqa: F401
+from repro.obs.trace import Span, Tracer, phase  # noqa: F401
 
 
 @dataclass

@@ -15,6 +15,14 @@ with a ``MetricsRegistry`` it observes ``queue_wait_s`` at admit,
 spread their replay wall evenly over the K tokens) and ``e2e_s`` at
 finish, labeled by model — the TTFT/ITL distributions Algorithm-1-style
 control loops need, at histogram-update cost.
+
+Beside the request spans, ``phase(name)`` marks a phase of the serve
+step (``gateway.step``, ``engine.decode``, ``engine.sync``, ...) on the
+profiler's own clock: it is ``jax.profiler.TraceAnnotation``, so its
+spans land in the same trace as the device's ops and exist only while a
+profile is being captured (about half a microsecond per span
+otherwise). Request spans are always on and exported; phase spans are
+read from a profiler trace.
 """
 from __future__ import annotations
 
@@ -22,7 +30,14 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional, Tuple
 
+import jax
+
 from repro.obs.metrics import MetricsRegistry
+
+# ``with phase("engine.decode"): ...`` -- a span of the serve step in the
+# profiler's trace; no keyword arguments (nothing reads them, and they
+# double the cost of a span)
+phase = jax.profiler.TraceAnnotation
 
 
 @dataclass

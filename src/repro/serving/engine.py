@@ -17,7 +17,8 @@ The unified schedule per step:
      ``chunk_tokens``, oldest admission first, throttled by what the
      decode tokens left of ``step_token_budget``. A chunk attends the
      slot's cached KV plus itself (causal); the LAST chunk's logits
-     sample the request's first token — that is when TTFT is stamped;
+     sample the request's first token, and TTFT is stamped when that
+     token reaches the host;
   3. decode    — one batched decode over every slot whose prefill is
      complete (including slots that finished in step 2: their first
      token joins this batch, matching the old admit-then-decode flow
@@ -99,6 +100,7 @@ from repro.models.transformer import (copy_paged_block, init_paged_cache,
                                       lm_chunk_prefill, lm_dense_verify,
                                       lm_paged_decode, lm_paged_verify,
                                       supports_chunked, supports_paged)
+from repro.obs.trace import phase
 from repro.serving.backend import BackendProfile
 from repro.serving.kvpool import BlockPool, RadixPrefixCache
 from repro.serving.sampling import SamplingParams, sample_rows
@@ -263,15 +265,17 @@ def _advance_impl(state, logits):
     keys folded from the uid streams) and advance the cursors of active
     rows. The logits are consumed here — they are never materialized on
     host."""
-    active = state["active"]
-    keys = jax.vmap(jax.random.fold_in)(state["key"], state["draws"])
-    nxt = sample_rows(logits, state["temp"], state["top_k"], state["top_p"],
-                      keys)
-    nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
-    state = dict(state,
-                 tokens=nxt[:, None],
-                 pos=jnp.where(active, state["pos"] + 1, state["pos"]),
-                 draws=jnp.where(active, state["draws"] + 1, state["draws"]))
+    with jax.named_scope("sample"):
+        active = state["active"]
+        keys = jax.vmap(jax.random.fold_in)(state["key"], state["draws"])
+        nxt = sample_rows(logits, state["temp"], state["top_k"],
+                          state["top_p"], keys)
+        nxt = jnp.where(active, nxt, 0).astype(jnp.int32)
+        state = dict(state,
+                     tokens=nxt[:, None],
+                     pos=jnp.where(active, state["pos"] + 1, state["pos"]),
+                     draws=jnp.where(active, state["draws"] + 1,
+                                     state["draws"]))
     return nxt, state
 
 
@@ -301,8 +305,9 @@ def _finish_bits(state, nxt, max_seq):
 
 def _retire_impl(state, nxt, max_seq):
     """On-device retirement: drop rows whose finish bits fired."""
-    bits = _finish_bits(state, nxt, max_seq)
-    return dict(state, active=state["active"] & (bits == 0)), bits
+    with jax.named_scope("sample"):
+        bits = _finish_bits(state, nxt, max_seq)
+        return dict(state, active=state["active"] & (bits == 0)), bits
 
 
 @dataclass(frozen=True)
@@ -379,16 +384,17 @@ def _fused_fns(step_fn, max_seq: int):
         return toks, bits, cache, state
 
     def _first(state, logits, idx, pos_vals, tables):
-        toks, state = _first_tokens_impl(state, logits, idx, pos_vals,
-                                         tables)
-        # device-side termination for first tokens too: an EOS straight
-        # out of prefill (or max_new_tokens=1) retires the row before it
-        # ever joins a decode batch. Non-idx active rows re-check their
-        # last token — a no-op by invariant (they survived their own
-        # step's bits or they would not be active).
-        allbits = _finish_bits(state, state["tokens"][:, 0], max_seq)
-        state = dict(state, active=state["active"] & (allbits == 0))
-        return toks, allbits[idx], state
+        with jax.named_scope("sample"):
+            toks, state = _first_tokens_impl(state, logits, idx, pos_vals,
+                                             tables)
+            # device-side termination for first tokens too: an EOS
+            # straight out of prefill (or max_new_tokens=1) retires the
+            # row before it ever joins a decode batch. Non-idx active rows
+            # re-check their last token — a no-op by invariant (they
+            # survived their own step's bits or they would not be active).
+            allbits = _finish_bits(state, state["tokens"][:, 0], max_seq)
+            state = dict(state, active=state["active"] & (allbits == 0))
+            return toks, allbits[idx], state
 
     return dict(
         fused_step=jax.jit(_fused, donate_argnums=(1, 2)),
@@ -1046,7 +1052,8 @@ class InferenceEngine:
                 raise InjectedFault(
                     f"injected step_error at step {self._fault.step_no}")
         try:
-            return self._step_inner()
+            with phase("engine.step"):
+                return self._step_inner()
         except BaseException:
             self.poisoned = True
             raise
@@ -1059,18 +1066,19 @@ class InferenceEngine:
         #    which case the request stays queued for a later step).
         #    Tombstoned (cancelled-in-queue) entries drain here for free.
         deny_kv = self._fault is not None and self._fault.deny_kv
-        for slot in self._slots:
-            while self._queue and self._queue[0].cancelled:
-                self._queue.popleft()
-                self._queue_tomb -= 1
-            if not self._queue:
-                break
-            if deny_kv:        # injected allocation failure: stay queued
-                break
-            if slot.done:
-                if not self._begin(slot.idx, self._queue[0]):
+        with phase("engine.admit"):
+            for slot in self._slots:
+                while self._queue and self._queue[0].cancelled:
+                    self._queue.popleft()
+                    self._queue_tomb -= 1
+                if not self._queue:
                     break
-                self._queue.popleft()
+                if deny_kv:    # injected allocation failure: stay queued
+                    break
+                if slot.done:
+                    if not self._begin(slot.idx, self._queue[0]):
+                        break
+                    self._queue.popleft()
         # requests sharing this step's batch — the cost ledger splits the
         # step's wall duration evenly across them (host-side list of ids
         # already in slot state; no device traffic)
@@ -1093,26 +1101,30 @@ class InferenceEngine:
                         key=lambda i: self._slots[i].order):
             if rem is not None and rem <= 0:
                 break
-            rem = self._prefill_step(i, self._slots[i], rem)
+            with phase("engine.prefill"):       # one chunk
+                rem = self._prefill_step(i, self._slots[i], rem)
         # 3b) ONE batched dispatch samples the first token of every slot
         #     whose last chunk just ran; they join this step's decode
-        self._finish_first_tokens()
+        with phase("engine.first_tokens"):
+            self._finish_first_tokens()
         # 4) decode all fully-prefilled slots: one fused device step per
         #    token, or a K-iteration burst when nothing is waiting to
         #    prefill (the offline/throughput path)
         active = [i for i, s in enumerate(self._slots)
                   if not s.done and not s.prefilling]
         if active:
-            if self._spec_ready(active):
-                self._decode_spec(active)
-            elif (self.decode_burst > 1 and self._queued() == 0
-                    and not any(s.prefilling for s in self._slots
-                                if not s.done)):
-                self._decode_burst(active)
-            else:
-                self._decode_once(active)
+            with phase("engine.decode"):
+                if self._spec_ready(active):
+                    self._decode_spec(active)
+                elif (self.decode_burst > 1 and self._queued() == 0
+                        and not any(s.prefilling for s in self._slots
+                                    if not s.done)):
+                    self._decode_burst(active)
+                else:
+                    self._decode_once(active)
         if self._obs is not None:
-            self._record_step(t0, step_uids, rem)
+            with phase("engine.record"):
+                self._record_step(t0, step_uids, rem)
         return self.drain_finished()
 
     def _record_step(self, t0: float, step_uids=None, rem=None) -> None:
@@ -1163,8 +1175,9 @@ class InferenceEngine:
         on device, the host just books the result."""
         nxt, bits, self.cache, self._dstate = self._fused_step(
             self.params, self.cache, self._dstate)
-        # servelint: disable=SL002 -- the designed per-step sync point
-        toks, bits = jax.device_get((nxt, bits))
+        with phase("engine.sync"):
+            # servelint: disable=SL002 -- the designed per-step sync point
+            toks, bits = jax.device_get((nxt, bits))
         t = time.perf_counter()
         tracer = self._obs.tracer if self._obs is not None else None
         for i in active:
@@ -1189,8 +1202,9 @@ class InferenceEngine:
         k = self.decode_burst
         toks, bits, self.cache, self._dstate = self._fused_burst(
             self.params, self.cache, self._dstate, k)
-        # servelint: disable=SL002 -- the designed per-burst sync point
-        toks, bits = jax.device_get((toks, bits))
+        with phase("engine.sync"):
+            # servelint: disable=SL002 -- the designed per-burst sync point
+            toks, bits = jax.device_get((toks, bits))
         counts: Dict[int, int] = {}
         for j in range(k):
             t = time.perf_counter()
@@ -1229,8 +1243,9 @@ class InferenceEngine:
         stay on device."""
         out, reason, self.cache, self._spec_cache, self._dstate = \
             self._spec_dispatch()
-        # servelint: disable=SL002 -- the designed per-verify sync point
-        out, reason = jax.device_get((out, reason))
+        with phase("engine.sync"):
+            # servelint: disable=SL002 -- the designed per-verify sync point
+            out, reason = jax.device_get((out, reason))
         t = time.perf_counter()
         k = self.spec.k
         counts: Dict[int, int] = {}
@@ -1323,13 +1338,16 @@ class InferenceEngine:
         toks, bits, self._dstate = self._first_fn(
             self._dstate, stacked, jnp.asarray(idx), jnp.asarray(pos_vals),
             self._stack_tables(pend, nb))
-        # servelint: disable=SL002 -- first-token ids must reach the host here
-        toks, bits = jax.device_get((toks, bits))
+        with phase("engine.sync"):
+            # servelint: disable=SL002 -- first-token ids must reach the host here
+            toks, bits = jax.device_get((toks, bits))
+        # TTFT stops here: the first token has reached the host
         t = time.perf_counter()
         tracer = self._obs.tracer if self._obs is not None else None
         for j, (slot, _) in enumerate(pend):
             tok = int(toks[j])
             uid = slot.req.uid
+            slot.res.ttft = t - slot.req.arrival_t
             slot.res.new_tokens.append(tok)
             self._deltas.append((uid, tok))
             slot.prefilling = False
@@ -1504,19 +1522,9 @@ class InferenceEngine:
         logits, new_kv = self._chunk_prefill(self.params, jnp.asarray(padded),
                                              ctx, jnp.int32(start),
                                              jnp.int32(n))
-        self._stamp_ttft(slot, start + n)
         self.cache = self._scatter_slot(self.cache, new_kv, jnp.int32(slot_id),
                                         jnp.int32(start), jnp.int32(n))
         return logits
-
-    def _stamp_ttft(self, slot: "_Slot", filled_after: int) -> None:
-        """TTFT convention: the clock stops when the last chunk's logits
-        are produced — the first token is determined there. The scatter
-        that follows is cache bookkeeping for FUTURE steps (it blocks on
-        the donated pool buffer) and must not count against TTFT, same
-        as the pre-chunking engine."""
-        if filled_after >= len(slot.prompt):
-            slot.res.ttft = time.perf_counter() - slot.req.arrival_t
 
     def _whole_prefill(self, slot_id: int, slot: "_Slot"):
         """Legacy one-shot prefill + whole-row insert (non-chunkable
@@ -1531,22 +1539,17 @@ class InferenceEngine:
             batch["src_embeds"] = jnp.asarray(se[None])
         logits, rcache = self._prefill(self.params, batch)
         self.cache = self._insert(self.cache, rcache, slot_id)
-        self._stamp_ttft(slot, len(slot.prompt))   # after insert: the slot
-        #       row must be live before the first decode (old convention)
         return logits
 
     def _finish_prefill(self, slot: "_Slot", logits) -> None:
-        """The last chunk just ran: register the prefix, stamp TTFT, and
-        queue the slot for this step's BATCHED first-token sample
+        """The last chunk just ran: register the prefix and queue the slot
+        for this step's BATCHED first-token sample
         (``_finish_first_tokens`` — one fused dispatch for every prefill
-        that completed this step). The usual termination rules apply
-        when the token lands there (max_new_tokens=1 must return exactly
-        one token, an EOS straight out of prefill must stop
-        generation)."""
-        res, req = slot.res, slot.req
+        that completed this step; TTFT is stamped when the token reaches
+        the host there). The usual termination rules apply when the
+        token lands (max_new_tokens=1 must return exactly one token, an
+        EOS straight out of prefill must stop generation)."""
         self._register_prefix(slot)
-        if not res.ttft:                 # _prefill_chunk stamps pre-scatter
-            res.ttft = time.perf_counter() - req.arrival_t
         if slot.spec_ok:
             # draft residency secured at admission: give the draft its
             # whole-prompt KV now, off the guarded decode path
@@ -1907,7 +1910,6 @@ class PagedInferenceEngine(InferenceEngine):
         ctx_kv = self._gather(self.cache, jnp.asarray(slot.table[:ctx]))
         logits, new_kv = self._prefill(self.params, jnp.asarray(padded),
                                        ctx_kv, jnp.int32(start), jnp.int32(n))
-        self._stamp_ttft(slot, start + n)
         self.cache = self._scatter(self.cache, new_kv,
                                    jnp.asarray(slot.table), jnp.int32(start),
                                    jnp.int32(n))

@@ -29,6 +29,8 @@ import heapq
 from dataclasses import dataclass, field
 from typing import Dict, List, Optional, Sequence, Tuple
 
+from repro.obs.trace import phase
+
 
 class PoolExhausted(RuntimeError):
     """No free block available (and nothing evictable)."""
@@ -238,21 +240,22 @@ class RadixPrefixCache:
         """Free up to ``n`` blocks, LRU leaves first. Returns #freed.
         One leaf scan up front; parents that become evictable leaves
         join the heap as their children go (no per-block rescans)."""
-        heap = [(node.t_access, node.block) for node in self._by_block.values()
-                if not node.children and self.pool.refcount(node.block) == 1]
-        heapq.heapify(heap)
-        freed = 0
-        while freed < n and heap:
-            _, bid = heapq.heappop(heap)
-            victim = self._by_block[bid]
-            parent = victim.parent
-            self._remove(victim)
-            freed += 1
-            if (parent is not self._root and not parent.children
-                    and self.pool.refcount(parent.block) == 1):
-                heapq.heappush(heap, (parent.t_access, parent.block))
-        self.stats.evicted_blocks += freed
-        return freed
+        with phase("kv.evict"):
+            heap = [(node.t_access, node.block) for node in self._by_block.values()
+                    if not node.children and self.pool.refcount(node.block) == 1]
+            heapq.heapify(heap)
+            freed = 0
+            while freed < n and heap:
+                _, bid = heapq.heappop(heap)
+                victim = self._by_block[bid]
+                parent = victim.parent
+                self._remove(victim)
+                freed += 1
+                if (parent is not self._root and not parent.children
+                        and self.pool.refcount(parent.block) == 1):
+                    heapq.heappush(heap, (parent.t_access, parent.block))
+            self.stats.evicted_blocks += freed
+            return freed
 
     def _remove(self, node: _RadixNode) -> None:
         assert not node.children

@@ -27,6 +27,7 @@ from typing import Dict, Iterator, List, Optional, Tuple
 import jax
 
 from repro.models import init_model
+from repro.obs.trace import phase
 from repro.models.transformer import supports_paged
 from repro.serving.backend import BACKENDS
 from repro.serving.engine import (DEFAULT_BLOCK_SIZE, InferenceEngine,
@@ -323,79 +324,80 @@ class ReplicaPool:
                                        incarnation=incarnation)
             raise InjectedFault(
                 f"injected spin_fail for {model}/{backend}#{incarnation}")
-        # servelint: disable=SL001 -- real wall interval: spin-up duration
-        t0 = time.perf_counter()
-        cfg = self.models[model]
-        warm = model in self._params and key in self._code
-        use_paged = self._use_paged(model, backend)
-        if model not in self._params:
-            self._params[model] = init_model(cfg, jax.random.PRNGKey(self.seed))
-        if key not in self._code:
-            self._code[key] = (
-                compile_paged_fns(cfg, BACKENDS[backend], self.max_seq,
-                                  self.block_size) if use_paged
-                else compile_fns(cfg, BACKENDS[backend], self.max_seq))
-        # ONE seed pool-wide: per-request PRNG streams are keyed by uid x
-        # draw index, so equal seeds make replicas interchangeable — the
-        # invariant deterministic retry-on-another-replica rests on
-        kw = dict(max_seq=self.max_seq,
-                  seed=self.seed,
-                  fns=self._code[key],
-                  chunk_tokens=self.chunk_tokens,
-                  step_token_budget=self.step_token_budget,
-                  decode_burst=self.decode_burst,
-                  spec=self._spec_draft(model),
-                  fault=(self.faults.injector(model, backend, incarnation)
-                         if self.faults is not None else None),
-                  obs=(self.obs.engine_obs(model, backend)
-                       if self.obs is not None else None))
-        if use_paged:
-            eng = PagedInferenceEngine(cfg, self._params[model],
-                                       BACKENDS[backend],
-                                       block_size=self.block_size, **kw)
-        else:
-            eng = InferenceEngine(cfg, self._params[model], BACKENDS[backend],
-                                  **kw)
-        # trigger compile/execute of the step functions before the replica
-        # counts as live (the dominant real cold-start cost when cold) —
-        # with obs muted, so compile-bound probe steps never land in the
-        # engine step-duration histograms
-        probe_obs, eng._obs = eng._obs, None
-        probe_fault, eng._fault = eng._fault, None   # probes aren't chaos targets
-        eng.run([Request(uid=-1, tokens=[1, 2, 3],
-                         sampling=SamplingParams(max_new_tokens=2))])
-        eng._obs = probe_obs
-        eng._fault = probe_fault
-        eng.health = ReplicaHealth(since=now)
-        eng.incarnation = incarnation
-        # servelint: disable=SL001 -- real wall interval: spin-up duration
-        dur = time.perf_counter() - t0
-        reps.append(eng)
-        entry = self.reg.entry(model, backend)
-        entry.replicas = len(reps)
-        entry.warm = 0
-        kind = "spin-warm" if warm else "spin-cold"
-        self.events.append(ScaleEvent(now, model, backend, len(reps) - 1,
-                                      len(reps), kind, dur))
-        self.cold_starts.append(
-            (f"{model}/{backend}/{'warm' if warm else 'cold'}", dur))
-        if self.obs is not None:
-            self.obs.registry.histogram(
-                "cold_start_s" if not warm else "warm_start_s",
-                model).observe(dur)
-            self.obs.events.append("scale", t=now, model=model,
-                                   backend=backend, before=len(reps) - 1,
-                                   after=len(reps), kind=kind,
-                                   duration_s=dur)
-            # open this replica's chip-second meter: the spin window
-            # (param build + compile + probes) is COLD chip-seconds; the
-            # metered clock starts now. perf_counter domain throughout —
-            # the same clock engine.step() stamps with.
-            eng._obs.meter = self.obs.ledger.replica_up(
-                model, backend, chips=entry.cost.chips, cold_s=dur,
-                t=time.perf_counter())  # servelint: disable=SL001 -- ledger is perf_counter domain (engine.step stamps feed it)
-            self._update_memory_gauges(model, now)
-            self._health_gauges(model)
+        with phase("pool.spin"):
+            # servelint: disable=SL001 -- real wall interval: spin-up duration
+            t0 = time.perf_counter()
+            cfg = self.models[model]
+            warm = model in self._params and key in self._code
+            use_paged = self._use_paged(model, backend)
+            if model not in self._params:
+                self._params[model] = init_model(cfg, jax.random.PRNGKey(self.seed))
+            if key not in self._code:
+                self._code[key] = (
+                    compile_paged_fns(cfg, BACKENDS[backend], self.max_seq,
+                                      self.block_size) if use_paged
+                    else compile_fns(cfg, BACKENDS[backend], self.max_seq))
+            # ONE seed pool-wide: per-request PRNG streams are keyed by uid x
+            # draw index, so equal seeds make replicas interchangeable — the
+            # invariant deterministic retry-on-another-replica rests on
+            kw = dict(max_seq=self.max_seq,
+                      seed=self.seed,
+                      fns=self._code[key],
+                      chunk_tokens=self.chunk_tokens,
+                      step_token_budget=self.step_token_budget,
+                      decode_burst=self.decode_burst,
+                      spec=self._spec_draft(model),
+                      fault=(self.faults.injector(model, backend, incarnation)
+                             if self.faults is not None else None),
+                      obs=(self.obs.engine_obs(model, backend)
+                           if self.obs is not None else None))
+            if use_paged:
+                eng = PagedInferenceEngine(cfg, self._params[model],
+                                           BACKENDS[backend],
+                                           block_size=self.block_size, **kw)
+            else:
+                eng = InferenceEngine(cfg, self._params[model], BACKENDS[backend],
+                                      **kw)
+            # trigger compile/execute of the step functions before the replica
+            # counts as live (the dominant real cold-start cost when cold) —
+            # with obs muted, so compile-bound probe steps never land in the
+            # engine step-duration histograms
+            probe_obs, eng._obs = eng._obs, None
+            probe_fault, eng._fault = eng._fault, None   # probes aren't chaos targets
+            eng.run([Request(uid=-1, tokens=[1, 2, 3],
+                             sampling=SamplingParams(max_new_tokens=2))])
+            eng._obs = probe_obs
+            eng._fault = probe_fault
+            eng.health = ReplicaHealth(since=now)
+            eng.incarnation = incarnation
+            # servelint: disable=SL001 -- real wall interval: spin-up duration
+            dur = time.perf_counter() - t0
+            reps.append(eng)
+            entry = self.reg.entry(model, backend)
+            entry.replicas = len(reps)
+            entry.warm = 0
+            kind = "spin-warm" if warm else "spin-cold"
+            self.events.append(ScaleEvent(now, model, backend, len(reps) - 1,
+                                          len(reps), kind, dur))
+            self.cold_starts.append(
+                (f"{model}/{backend}/{'warm' if warm else 'cold'}", dur))
+            if self.obs is not None:
+                self.obs.registry.histogram(
+                    "cold_start_s" if not warm else "warm_start_s",
+                    model).observe(dur)
+                self.obs.events.append("scale", t=now, model=model,
+                                       backend=backend, before=len(reps) - 1,
+                                       after=len(reps), kind=kind,
+                                       duration_s=dur)
+                # open this replica's chip-second meter: the spin window
+                # (param build + compile + probes) is COLD chip-seconds; the
+                # metered clock starts now. perf_counter domain throughout —
+                # the same clock engine.step() stamps with.
+                eng._obs.meter = self.obs.ledger.replica_up(
+                    model, backend, chips=entry.cost.chips, cold_s=dur,
+                    t=time.perf_counter())  # servelint: disable=SL001 -- ledger is perf_counter domain (engine.step stamps feed it)
+                self._update_memory_gauges(model, now)
+                self._health_gauges(model)
 
     def _spin_down(self, model: str, backend: str, target: int,
                    now: float) -> None:

@@ -46,6 +46,7 @@ from collections import deque
 from dataclasses import dataclass, field, replace
 from typing import Deque, Dict, List, Optional, Tuple
 
+from repro.obs.trace import phase
 from repro.serving.engine import GenResult, Request
 from repro.serving.replica_pool import ReplicaPool
 
@@ -397,94 +398,97 @@ class RequestScheduler:
     def step(self, now: Optional[float] = None) -> List[Tuple[_Key, GenResult]]:
         """One serve-loop iteration over the whole pool: admit queued work,
         run ONE batched decode on every engine with work, reap finished."""
-        now = time.perf_counter() if now is None else now
-        self.stats.steps += 1
-        self.dispatch(now)
-        out: List[Tuple[_Key, GenResult]]
-        out, self._reaped = self._reaped, []
-        out = [(k, self._absorb_retries(r)) for k, r in out]
-        self._deltas = []
-        flight = self._obs.flight if self._obs is not None else None
-        for key, eng in list(self.pool.engines()):
-            if not eng.has_work():
-                continue
-            entry = self.reg.entry(*key)
-            try:
-                results = eng.step()
-            except Exception as exc:
-                # the flight ring holds the steps leading INTO the crash;
-                # dump before anything else happens to the replica
-                if flight is not None:
-                    flight.note_exception(key[0], exc, now)
-                report = getattr(self.pool, "report_step_failure", None)
-                if not self.cfg.contain_failures or report is None:
-                    raise
-                # containment: the circuit breaker degrades (replica keeps
-                # its state, retries next step) or quarantines (replica
-                # leaves placement; its in-flight work comes back as an
-                # evacuation list we resubmit deterministically). Results
-                # and deltas booked BEFORE a mid-step crash are salvaged —
-                # their device work completed, and retry dedup means
-                # nothing is ever emitted twice.
-                self._note("step_error", key[0], now, error=repr(exc))
-                evac = report(key[0], key[1], eng, exc, now)
-                results = eng.drain_finished()
-                self._deltas.extend(self._filter_deltas(eng.drain_deltas()))
-                if evac is not None:
-                    self.stats.quarantines += 1
-                    self._resubmit(key, evac, now)
-            else:
-                ok = getattr(self.pool, "note_step_ok", None)
-                if ok is not None:
-                    ok(eng, now)
-                self._deltas.extend(self._filter_deltas(eng.drain_deltas()))
-            for res in results:
-                res = self._absorb_retries(res)
-                entry.active_requests = max(0, entry.active_requests - 1)
-                # stamp with the step's OWN clock: mixing perf_counter
-                # into a simulated `now` skewed the telemetry window
-                self.tel.record_latency(key[0], now, res.latency)
-                self.stats.completed += 1
-                if res.timed_out and flight is not None:
-                    flight.note_expiry(now)
-                out.append((key, res))
-        # draining replicas that emptied (or blew their deadline) retire
-        # here; deadline evacuations are resubmitted like quarantines
-        drains = getattr(self.pool, "finish_drains", None)
-        if drains is not None:
-            for dkey, evac in drains(now):
-                self._resubmit(dkey, evac, now)
-        # paged-plane gauges: pool pressure / occupancy / prefix hit-rate
-        # land in the same telemetry the Orchestrator ticks on, so Spin
-        # can treat a block-starved service as a loaded one
-        for model in {m for m, _ in self._queues}:
-            stats = self.pool.kv_stats(model)
-            if stats:
-                for name, value in stats.items():
-                    self.tel.record_gauge(model, name, now, value)
-            # token-denominated load: queued prompt tokens + unfilled
-            # prefill backlog on the engines — the gauge that actually
-            # predicts time-to-first-token under chunked prefill
-            qtok = sum(self._queue_tokens(q)
-                       for (m, _b), q in self._queues.items() if m == model)
-            self.tel.record_gauge(model, "queue_tokens", now, float(qtok))
-            self.tel.record_gauge(model, "backlog_tokens", now,
-                                  float(qtok + self.pool.backlog_tokens(model)))
-            # resident KV bytes, labeled by occupancy state (composite
-            # label -> kv_pool_bytes{model=...,state=used|free} in the
-            # exposition)
-            if self._obs is not None:
-                # getattr: stub pools in tests duck-type ReplicaPool
-                kv_bytes = getattr(self.pool, "kv_bytes", None)
-                kb = kv_bytes(model) if kv_bytes is not None else None
-                if kb is not None:
-                    used, free = kb
-                    reg = self._obs.registry
-                    reg.gauge("kv_pool_bytes",
-                              f"{model}|state=used").set(float(used), now)
-                    reg.gauge("kv_pool_bytes",
-                              f"{model}|state=free").set(float(free), now)
-        return out
+        with phase("scheduler.step"):
+            now = time.perf_counter() if now is None else now
+            self.stats.steps += 1
+            with phase("scheduler.dispatch"):
+                self.dispatch(now)
+            out: List[Tuple[_Key, GenResult]]
+            out, self._reaped = self._reaped, []
+            out = [(k, self._absorb_retries(r)) for k, r in out]
+            self._deltas = []
+            flight = self._obs.flight if self._obs is not None else None
+            for key, eng in list(self.pool.engines()):
+                if not eng.has_work():
+                    continue
+                entry = self.reg.entry(*key)
+                try:
+                    results = eng.step()
+                except Exception as exc:
+                    # the flight ring holds the steps leading INTO the crash;
+                    # dump before anything else happens to the replica
+                    if flight is not None:
+                        flight.note_exception(key[0], exc, now)
+                    report = getattr(self.pool, "report_step_failure", None)
+                    if not self.cfg.contain_failures or report is None:
+                        raise
+                    # containment: the circuit breaker degrades (replica keeps
+                    # its state, retries next step) or quarantines (replica
+                    # leaves placement; its in-flight work comes back as an
+                    # evacuation list we resubmit deterministically). Results
+                    # and deltas booked BEFORE a mid-step crash are salvaged —
+                    # their device work completed, and retry dedup means
+                    # nothing is ever emitted twice.
+                    self._note("step_error", key[0], now, error=repr(exc))
+                    evac = report(key[0], key[1], eng, exc, now)
+                    results = eng.drain_finished()
+                    self._deltas.extend(self._filter_deltas(eng.drain_deltas()))
+                    if evac is not None:
+                        self.stats.quarantines += 1
+                        self._resubmit(key, evac, now)
+                else:
+                    ok = getattr(self.pool, "note_step_ok", None)
+                    if ok is not None:
+                        ok(eng, now)
+                    self._deltas.extend(self._filter_deltas(eng.drain_deltas()))
+                for res in results:
+                    res = self._absorb_retries(res)
+                    entry.active_requests = max(0, entry.active_requests - 1)
+                    # stamp with the step's OWN clock: mixing perf_counter
+                    # into a simulated `now` skewed the telemetry window
+                    self.tel.record_latency(key[0], now, res.latency)
+                    self.stats.completed += 1
+                    if res.timed_out and flight is not None:
+                        flight.note_expiry(now)
+                    out.append((key, res))
+            # draining replicas that emptied (or blew their deadline) retire
+            # here; deadline evacuations are resubmitted like quarantines
+            drains = getattr(self.pool, "finish_drains", None)
+            if drains is not None:
+                for dkey, evac in drains(now):
+                    self._resubmit(dkey, evac, now)
+            with phase("scheduler.gauges"):
+                # paged-plane gauges: pool pressure / occupancy / prefix hit-rate
+                # land in the same telemetry the Orchestrator ticks on, so Spin
+                # can treat a block-starved service as a loaded one
+                for model in {m for m, _ in self._queues}:
+                    stats = self.pool.kv_stats(model)
+                    if stats:
+                        for name, value in stats.items():
+                            self.tel.record_gauge(model, name, now, value)
+                    # token-denominated load: queued prompt tokens + unfilled
+                    # prefill backlog on the engines — the gauge that actually
+                    # predicts time-to-first-token under chunked prefill
+                    qtok = sum(self._queue_tokens(q)
+                               for (m, _b), q in self._queues.items() if m == model)
+                    self.tel.record_gauge(model, "queue_tokens", now, float(qtok))
+                    self.tel.record_gauge(model, "backlog_tokens", now,
+                                          float(qtok + self.pool.backlog_tokens(model)))
+                    # resident KV bytes, labeled by occupancy state (composite
+                    # label -> kv_pool_bytes{model=...,state=used|free} in the
+                    # exposition)
+                    if self._obs is not None:
+                        # getattr: stub pools in tests duck-type ReplicaPool
+                        kv_bytes = getattr(self.pool, "kv_bytes", None)
+                        kb = kv_bytes(model) if kv_bytes is not None else None
+                        if kb is not None:
+                            used, free = kb
+                            reg = self._obs.registry
+                            reg.gauge("kv_pool_bytes",
+                                      f"{model}|state=used").set(float(used), now)
+                            reg.gauge("kv_pool_bytes",
+                                      f"{model}|state=free").set(float(free), now)
+            return out
 
     def drain_deltas(self) -> List[Tuple[int, int]]:
         """Fetch the latest step's (uid, token) streaming increments, in
