@@ -31,6 +31,47 @@ def init_model(cfg: ModelConfig, key, dtype=jnp.float32) -> Params:
     return transformer.init_lm(cfg, key, dtype)
 
 
+# Layer weights the model reads only through ``cast_weight(w, <compute
+# dtype>)``, by the sub-block that holds them: the GQA projections and
+# the FFN matrices (dense, shared-expert or routed-expert).
+_GQA_WEIGHTS = frozenset({"wq", "wk", "wv", "wo", "bq", "bk", "bv", "bo"})
+_FFN_WEIGHTS = frozenset({"w_gate", "w_up", "w_down"})
+
+
+def serving_params(cfg: ModelConfig, params: Params) -> Params:
+    """``params`` as a serving replica keeps them: each weight of
+    ``layers``/``prefix_layers`` that the model reads only through
+    ``cast_weight`` into the compute dtype is stored in that dtype, so
+    no step casts it again. The matmuls see the same operands. All else
+    keeps its dtype: embeddings and head, norms, the MoE router, MLA
+    projections and the SSM / hybrid / enc-dec families.
+
+    Converts in place, leaf by leaf: each float32 array is dropped as
+    its cast is stored, so the peak stays one leaf above the float32
+    params. Returns ``params`` unchanged where the compute dtype is
+    float32."""
+    dtype = transformer._adtype(cfg)
+    if dtype == jnp.float32:
+        return params
+    roles = {"ffn": _FFN_WEIGHTS}
+    if cfg.attention_type == "gqa":
+        roles["attn"] = _GQA_WEIGHTS
+
+    def cast(tree: Params, names) -> None:
+        for k, v in tree.items():
+            if isinstance(v, dict):
+                cast(v, names)              # the MoE's shared experts
+            elif k in names:
+                tree[k] = v.astype(dtype)
+
+    stack = [params["layers"]] if "layers" in params else []
+    for block in stack + params.get("prefix_layers", []):
+        for role, names in roles.items():
+            if role in block:
+                cast(block[role], names)
+    return params
+
+
 def model_forward(params: Params, cfg: ModelConfig, batch: dict, *,
                   remat: bool = True, q_chunk: int = 512, moe_cf=1.25,
                   return_hidden: bool = False

@@ -6,7 +6,9 @@ expensive (param init/load + XLA compile) and measured; two warm layers
 cut it down:
 
   * param cache — model weights stay resident after scale-to-zero (the
-    paper's "warm pool"), so a re-spin skips ``init_model``;
+    paper's "warm pool"), so a re-spin skips ``init_model``; they are
+    kept as ``serving_params`` stores them (layer matmul weights in the
+    compute dtype);
   * code cache  — the jitted prefill/decode executables for a service
     are shared across its replicas and survive scale-to-zero, so only
     the FIRST replica of a service ever pays XLA compile (replica fork,
@@ -25,8 +27,9 @@ from dataclasses import dataclass
 from typing import Dict, Iterator, List, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 
-from repro.models import init_model
+from repro.models import init_model, serving_params
 from repro.obs.trace import phase
 from repro.models.transformer import supports_paged
 from repro.serving.backend import BACKENDS
@@ -37,6 +40,14 @@ from repro.serving.faults import FaultPlan, InjectedFault
 from repro.serving.sampling import SamplingParams
 
 _Key = Tuple[str, str]
+
+
+def _compute_dtype_share(params) -> float:
+    """Share of the parameter elements stored below float32: those that
+    ``serving_params`` cast to the compute dtype."""
+    leaves = jax.tree_util.tree_leaves(params)
+    low = sum(x.size for x in leaves if x.dtype != jnp.float32)
+    return low / max(1, sum(x.size for x in leaves))
 
 
 @dataclass
@@ -284,7 +295,21 @@ class ReplicaPool:
             if m == model:
                 e.warm = 0
 
-    def _spec_draft(self, model: str) -> Optional[SpecDraft]:
+    def _warm_params(self, name: str, cfg, now: float):
+        """``name``'s entry of the warm param cache, built on first use:
+        initialised, then stored for serving under ``pool.store_params``,
+        which also sets ``params_compute_dtype_share``."""
+        if name not in self._params:
+            params = init_model(cfg, jax.random.PRNGKey(self.seed))
+            with phase("pool.store_params"):
+                params = self._params[name] = serving_params(cfg, params)
+            if self.obs is not None:
+                self.obs.registry.gauge(
+                    "params_compute_dtype_share", name).set(
+                        _compute_dtype_share(params), stamp=now)
+        return self._params[name]
+
+    def _spec_draft(self, model: str, now: float) -> Optional[SpecDraft]:
         """Resolve the pool's SpecConfig into a SpecDraft for ``model``
         (None when spec is off or the draft arch IS the target — a model
         never drafts for itself). Draft weights ride the same warm param
@@ -301,9 +326,8 @@ class ReplicaPool:
                 raise ValueError(f"unknown spec draft arch {arch!r}")
             dcfg = dataclasses.replace(ARCHS[arch].reduced(),
                                        dtype=self.models[model].dtype)
-        if arch not in self._params:
-            self._params[arch] = init_model(dcfg, jax.random.PRNGKey(self.seed))
-        return SpecDraft(cfg=dcfg, params=self._params[arch], k=self.spec.k)
+        return SpecDraft(cfg=dcfg, params=self._warm_params(arch, dcfg, now),
+                         k=self.spec.k)
 
     # -- internals -------------------------------------------------------
     def _spin_up(self, model: str, backend: str, now: float) -> None:
@@ -330,8 +354,7 @@ class ReplicaPool:
             cfg = self.models[model]
             warm = model in self._params and key in self._code
             use_paged = self._use_paged(model, backend)
-            if model not in self._params:
-                self._params[model] = init_model(cfg, jax.random.PRNGKey(self.seed))
+            params = self._warm_params(model, cfg, now)
             if key not in self._code:
                 self._code[key] = (
                     compile_paged_fns(cfg, BACKENDS[backend], self.max_seq,
@@ -346,18 +369,17 @@ class ReplicaPool:
                       chunk_tokens=self.chunk_tokens,
                       step_token_budget=self.step_token_budget,
                       decode_burst=self.decode_burst,
-                      spec=self._spec_draft(model),
+                      spec=self._spec_draft(model, now),
                       fault=(self.faults.injector(model, backend, incarnation)
                              if self.faults is not None else None),
                       obs=(self.obs.engine_obs(model, backend)
                            if self.obs is not None else None))
             if use_paged:
-                eng = PagedInferenceEngine(cfg, self._params[model],
+                eng = PagedInferenceEngine(cfg, params,
                                            BACKENDS[backend],
                                            block_size=self.block_size, **kw)
             else:
-                eng = InferenceEngine(cfg, self._params[model], BACKENDS[backend],
-                                      **kw)
+                eng = InferenceEngine(cfg, params, BACKENDS[backend], **kw)
             # trigger compile/execute of the step functions before the replica
             # counts as live (the dominant real cold-start cost when cold) —
             # with obs muted, so compile-bound probe steps never land in the

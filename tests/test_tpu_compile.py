@@ -4,9 +4,12 @@ Interpret mode never checks Mosaic's tiling rules (a block's last two
 dims divisible by (8, 128) or equal to the array's); the TPU compiler,
 installed here, does, for a chip that is described and not attached.
 Each case compiles one kernel in bf16 at a published head geometry and
-checks that the Mosaic kernel is in the program. Nothing runs.
+checks that the Mosaic kernel is in the program; one compiles the fused
+decode step and checks where the layer weights are cast. Nothing runs.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -15,6 +18,10 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import ARCHS
 from repro.kernels import ops
+from repro.models import init_model, serving_params
+from repro.models.transformer import init_paged_cache
+from repro.serving.backend import BACKENDS
+from repro.serving.engine import compile_paged_fns, init_device_state
 
 ARCH_NAMES = ["smollm-360m", "phi3-medium-14b", "glm4-9b"]
 BS = 16                        # the engine's block size
@@ -79,3 +86,35 @@ def test_paged_prefill_compiles_for_v5e(arch, sb, ctx_blocks, block,
         ((nb, hkv, block, d), bf), ((hkv, sb, d), bf), ((hkv, sb, d), bf),
         ((ctx_blocks,), i32), ((), i32), ((), i32)], one_chip)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("served", [False, True])
+def test_fused_step_casts_layer_weights_only_from_f32(served, one_chip):
+    """smollm-360m's paged fused decode step at two layers and published
+    widths: from float32 params the compiler casts each stacked layer
+    weight to bf16 every step (a convert of a [2, ...] array); from
+    ``serving_params`` there is no such convert."""
+    cfg = dataclasses.replace(ARCHS["smollm-360m"], num_layers=2)
+    be = BACKENDS["vllm"]
+    nbseq = MAX_SEQ // BS
+
+    def build(k):
+        params = init_model(cfg, k)
+        return serving_params(cfg, params) if served else params
+
+    def place(tree):
+        return jax.tree_util.tree_map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                           sharding=one_chip), tree)
+
+    params = place(jax.eval_shape(build, jax.random.PRNGKey(0)))
+    cache = place(jax.eval_shape(
+        lambda: init_paged_cache(cfg, be.max_batch * nbseq, BS)))
+    state = place(jax.eval_shape(
+        lambda: init_device_state(be.max_batch, nbseq)))
+    fns = compile_paged_fns(cfg, be, MAX_SEQ, BS)
+    with ops.kernel_dispatch("mosaic"):
+        text = fns.fused_step.lower(params, cache, state).compile().as_text()
+    stack_casts = re.findall(r"= bf16\[2,[\d,]+\]\S* convert\(", text)
+    assert "tpu_custom_call" in text
+    assert bool(stack_casts) != served
